@@ -12,6 +12,8 @@ Run: PYTHONPATH=src python -m benchmarks.run [section ...]
 import pathlib
 import sys
 
+from repro.launch.compile_cache import configure_compile_cache
+
 from . import (common, fig2_accuracy, fig2_latency, fig6_numerical,
                fig7_colosseum, kernel_perf, roofline, solver_perf, sweep_perf)
 
@@ -29,6 +31,7 @@ SECTIONS = {
 
 def main() -> None:
     picks = sys.argv[1:] or list(SECTIONS)
+    configure_compile_cache()
     print("name,us_per_call,derived")
     for name in picks:
         SECTIONS[name]()

@@ -707,8 +707,6 @@ def _sharded_solve_fn(mesh, axis: str, flexible: bool, inner: str):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.compat import shard_map_nocheck
-
     def body(lat_ok, grid, price, cap, alive0, cost, load, link_cap,
              incidence, group):
         admitted, alloc_idx, _, _ = _batch_solve_coupled(
@@ -717,11 +715,11 @@ def _sharded_solve_fn(mesh, axis: str, flexible: bool, inner: str):
         return admitted, alloc_idx
 
     cells, rep = P(axis), P()
-    fn = shard_map_nocheck(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(cells, rep, cells, cells, cells, rep, cells, rep, cells,
                   cells),
-        out_specs=(cells, cells))
+        out_specs=(cells, cells), check_vma=False)
     return jax.jit(fn)
 
 
@@ -740,8 +738,6 @@ def _sharded_serve_fn(mesh, axis: str, flexible: bool, inner: str):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.compat import shard_map_nocheck
-
     def body(lat_ok, grid, price, cap, alive0, cost, load, link_cap,
              incidence, group):
         admitted, alloc_idx, occupied, used = _batch_solve_coupled(
@@ -751,11 +747,11 @@ def _sharded_serve_fn(mesh, axis: str, flexible: bool, inner: str):
         return packed, residual, used
 
     cells, rep = P(axis), P()
-    fn = shard_map_nocheck(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(cells, rep, cells, cells, cells, rep, cells, rep, cells,
                   cells),
-        out_specs=(cells, cells, cells))
+        out_specs=(cells, cells, cells), check_vma=False)
     return jax.jit(fn)
 
 

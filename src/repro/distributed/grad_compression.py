@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .compat import shard_map_nocheck
-
 __all__ = ["compress", "decompress", "ef_allreduce", "init_error"]
 
 
@@ -60,8 +58,8 @@ def ef_allreduce(grads, errors, mesh, axis: str = "pod"):
             s_max = jax.lax.pmax(s_loc, axis)   # conservative shared scale
             return summed.astype(jnp.float32) * s_max / n
 
-        fn = shard_map_nocheck(reduce_local, mesh=mesh,
-                               in_specs=(P(), P()), out_specs=P())
+        fn = jax.shard_map(reduce_local, mesh=mesh, in_specs=(P(), P()),
+                           out_specs=P(), check_vma=False)
         return fn(q, scale), new_err
 
     flat_g, tdef = jax.tree_util.tree_flatten(grads)

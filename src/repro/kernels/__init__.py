@@ -1,4 +1,4 @@
-"""Pallas TPU kernels (compiled on TPU/GPU, interpret-mode elsewhere)."""
+"""Pallas TPU kernels: compiled on the TPU, interpreted on any other backend."""
 
 import jax
 

@@ -13,19 +13,31 @@ only ``dryrun.py`` sets XLA_FLAGS for 512 host devices before first jax init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_test_mesh", "make_cells_mesh", "HW"]
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    Newer JAX defaults mesh axes to ``Explicit`` sharding, under which a
+    scatter into a sharded operand must name its ``out_sharding``. The
+    programs here leave placement to the compiler (``NamedSharding`` inputs,
+    ``shard_map`` bodies), which is what ``Auto`` axes mean.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 4):
     """Small mesh for CI-scale sharding tests (8 fake devices)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_cells_mesh(n_devices: int | None = None, *, axis: str = "cells"):
@@ -35,7 +47,7 @@ def make_cells_mesh(n_devices: int | None = None, *, axis: str = "cells"):
     visible devices; pass ``n_devices`` to restrict (must divide nothing —
     any count works, lighter shards are padded)."""
     n = len(jax.devices()) if n_devices is None else int(n_devices)
-    return jax.make_mesh((n,), (axis,))
+    return _auto_mesh((n,), (axis,))
 
 
 class HW:

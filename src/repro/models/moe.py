@@ -25,8 +25,6 @@ from jax.sharding import PartitionSpec as P
 
 from .layers import dense_init
 
-from repro.distributed.compat import shard_map_nocheck
-
 __all__ = ["moe_init", "moe_apply"]
 
 
@@ -193,8 +191,9 @@ def moe_apply(params, x, cfg, *, impl: str | None = None, mesh=None,
     else:
         raise ValueError(impl)
 
-    return shard_map_nocheck(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(w_specs, x_spec),
         out_specs=x_spec,
+        check_vma=False,
     )(params, x)

@@ -22,8 +22,9 @@ def _run(body: str):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, numpy as np, jax.numpy as jnp
         import dataclasses
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
@@ -112,7 +113,8 @@ def test_sharded_train_step_runs_and_matches_single_device():
 
 def test_ef_allreduce_cross_pod():
     _run("""
-        pod_mesh = jax.make_mesh((8,), ("pod",))
+        pod_mesh = jax.make_mesh((8,), ("pod",),
+                                 axis_types=(AxisType.Auto,))
         from repro.distributed.grad_compression import ef_allreduce, init_error
         grads = {"w": jax.random.normal(jax.random.PRNGKey(0), (16, 16))}
         errs = init_error(grads)
