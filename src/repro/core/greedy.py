@@ -24,6 +24,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..trace import span
+
 from . import semantics
 from .sfesp import (DeviceStack, ShardedStack, device_stack,
                     device_stack_sharded, lexicographic_cost, next_pow2,
@@ -328,6 +330,9 @@ def _batch_solve(lat_ok, grid, price, cap, alive0, cost,
     bit-domain jnp round. The MinRes path (flexible=False) needs each task's
     OWN min-cost allocation, so it keeps the vmapped dense round regardless
     of ``inner``.
+
+    Returns ``(admitted, alloc_idx, occupied, rounds)``: ``rounds`` is the
+    loop's int32 trip count, the admission rounds the batch took.
     """
     B, tmax, A = lat_ok.shape
     m = grid.shape[1]
@@ -338,21 +343,22 @@ def _batch_solve(lat_ok, grid, price, cap, alive0, cost,
             def f(state_b, lat_ok_b, price_b, cap_b):
                 return _round(state_b, lat_ok_b, grid, price_b, cap_b, cost,
                               False, None)
-            return jax.vmap(f)(state, lat_ok, price, cap)
+            return (*jax.vmap(f)(state[:4], lat_ok, price, cap), state[4] + 1)
 
         def cond(state):
             return jnp.any(state[3])
 
         init = (jnp.zeros((B, tmax), bool), jnp.full((B, tmax), -1, jnp.int32),
-                jnp.zeros((B, m), grid.dtype), alive0)
-        admitted, alloc_idx, occupied, _ = jax.lax.while_loop(cond, body, init)
-        return admitted, alloc_idx, occupied
+                jnp.zeros((B, m), grid.dtype), alive0, jnp.int32(0))
+        admitted, alloc_idx, occupied, _, rounds = jax.lax.while_loop(
+            cond, body, init)
+        return admitted, alloc_idx, occupied, rounds
 
     lat_bits = _pack_bits(lat_ok)                          # (B, T, W) u32
     round_fn = _flex_round_fn(inner, lat_bits, grid, price, cap, A)
 
     def body(state):
-        admitted, alloc_idx, occupied, alive = state
+        admitted, alloc_idx, occupied, alive, rounds = state
         v, tau, best_a = round_fn(occupied, alive)
         admit = v > -jnp.inf
         admitted = admitted.at[bidx, tau].set(admitted[bidx, tau] | admit)
@@ -362,23 +368,25 @@ def _batch_solve(lat_ok, grid, price, cap, alive0, cost,
         # the admitted task leaves the candidate set; a round with nothing
         # feasible retires the whole instance (the oracle's line-15 mass drop)
         alive = alive.at[bidx, tau].set(False) & admit[:, None]
-        return admitted, alloc_idx, occupied, alive
+        return admitted, alloc_idx, occupied, alive, rounds + 1
 
     def cond(state):
         return jnp.any(state[3])
 
     init = (jnp.zeros((B, tmax), bool), jnp.full((B, tmax), -1, jnp.int32),
-            jnp.zeros((B, m), grid.dtype), alive0)
-    admitted, alloc_idx, occupied, _ = jax.lax.while_loop(cond, body, init)
-    return admitted, alloc_idx, occupied
+            jnp.zeros((B, m), grid.dtype), alive0, jnp.int32(0))
+    admitted, alloc_idx, occupied, _, rounds = jax.lax.while_loop(
+        cond, body, init)
+    return admitted, alloc_idx, occupied, rounds
 
 
 @functools.partial(jax.jit, static_argnames=("flexible", "inner"))
 def _greedy_jax_batch(lat_ok, grid, price, cap, alive0, cost,
                       flexible: bool = True, inner: str = "jnp"):
     """Solve B padded instances in ONE device program (see _batch_solve)."""
-    return _batch_solve(lat_ok, grid, price, cap, alive0, cost,
-                        flexible, inner)
+    admitted, alloc_idx, occupied, _ = _batch_solve(
+        lat_ok, grid, price, cap, alive0, cost, flexible, inner)
+    return admitted, alloc_idx, occupied
 
 
 def _batch_solve_coupled(lat_ok, grid, price, cap, alive0, cost,
@@ -405,6 +413,9 @@ def _batch_solve_coupled(lat_ok, grid, price, cap, alive0, cost,
     so infeasibility is permanent. Uncoupled cells (all-zero incidence rows)
     are singleton groups and admit every round, exactly like the uncoupled
     engine.
+
+    Returns ``(admitted, alloc_idx, occupied, used, rounds)``, ``rounds``
+    the loop's int32 trip count.
     """
     B, tmax, A = lat_ok.shape
     m = grid.shape[1]
@@ -429,7 +440,7 @@ def _batch_solve_coupled(lat_ok, grid, price, cap, alive0, cost,
             return jax.vmap(f)(lat_ok, price, cap, occupied, alive)
 
     def body(state):
-        admitted, alloc_idx, occupied, alive, used = state
+        admitted, alloc_idx, occupied, alive, used, rounds = state
         rem = link_cap - used                                        # (L,)
         headroom = jnp.where(inc_b, rem[None, :], jnp.inf).min(-1)   # (B,)
         link_ok = load <= headroom[:, None] + 1e-9                   # (B, T)
@@ -448,17 +459,17 @@ def _batch_solve_coupled(lat_ok, grid, price, cap, alive0, cost,
         alive = jnp.where(admit[:, None], alive.at[bidx, tau].set(False),
                           alive)
         alive = alive & (v > -jnp.inf)[:, None]
-        return admitted, alloc_idx, occupied, alive, used
+        return admitted, alloc_idx, occupied, alive, used, rounds + 1
 
     def cond(state):
         return jnp.any(state[3])
 
     init = (jnp.zeros((B, tmax), bool), jnp.full((B, tmax), -1, jnp.int32),
             jnp.zeros((B, m), grid.dtype), alive0,
-            jnp.zeros(link_cap.shape, grid.dtype))
-    admitted, alloc_idx, occupied, _, used = \
+            jnp.zeros(link_cap.shape, grid.dtype), jnp.int32(0))
+    admitted, alloc_idx, occupied, _, used, rounds = \
         jax.lax.while_loop(cond, body, init)
-    return admitted, alloc_idx, occupied, used
+    return admitted, alloc_idx, occupied, used, rounds
 
 
 @functools.partial(jax.jit, static_argnames=("flexible", "inner"))
@@ -466,7 +477,7 @@ def _greedy_jax_batch_coupled(lat_ok, grid, price, cap, alive0, cost,
                               load, link_cap, incidence, group,
                               flexible: bool = True, inner: str = "jnp"):
     """Coupled batch solve in ONE device program (see _batch_solve_coupled)."""
-    admitted, alloc_idx, occupied, _ = _batch_solve_coupled(
+    admitted, alloc_idx, occupied, _, _ = _batch_solve_coupled(
         lat_ok, grid, price, cap, alive0, cost, load, link_cap, incidence,
         group, flexible, inner)
     return admitted, alloc_idx, occupied
@@ -476,18 +487,21 @@ def _greedy_jax_batch_coupled(lat_ok, grid, price, cap, alive0, cost,
 # Fused serving entry points: device-resident inputs, packed decision output
 # ---------------------------------------------------------------------------
 
-def _extract_packed(admitted, alloc_idx, occupied, cap):
+def _extract_packed(admitted, alloc_idx, occupied, cap, rounds):
     """Fuse decision extraction into the device program.
 
     Instead of shipping the full (B, Tmax) solution tables to the host and
     unpacking per task in Python, pack each batch row's decision into ONE
-    compact int32 row: ``[admitted bitmask (ceil(T/32) words) | alloc_idx]``,
-    plus the (B, m) residual capacities. The serving loop reads back a single
-    small buffer per tick.
+    compact int32 row: ``[admitted bitmask (ceil(T/32) words) | alloc_idx |
+    rounds]``, plus the (B, m) residual capacities. The trailing column is
+    the admission loop's trip count (the same in every row of a program), so
+    the round counter costs no transfer of its own. The serving loop reads
+    back a single small buffer per tick.
     """
     bits = _pack_bits(admitted)                           # (B, WT) u32
     packed = jnp.concatenate(
-        [bits.astype(jnp.int32), alloc_idx.astype(jnp.int32)], axis=1)
+        [bits.astype(jnp.int32), alloc_idx.astype(jnp.int32),
+         jnp.broadcast_to(rounds, (admitted.shape[0], 1))], axis=1)
     return packed, cap - occupied
 
 
@@ -498,11 +512,11 @@ def _serve_batch(lat_ok, grid, price, cap, alive0, cost,
 
     Inputs are expected to be ALREADY device-resident (a
     :class:`~repro.core.sfesp.DeviceStack`): nothing is re-uploaded per call.
-    Returns ``(packed (B, WT+Tmax) i32, residual (B, m))``.
+    Returns ``(packed (B, WT+Tmax+1) i32, residual (B, m))``.
     """
-    admitted, alloc_idx, occupied = _batch_solve(
+    admitted, alloc_idx, occupied, rounds = _batch_solve(
         lat_ok, grid, price, cap, alive0, cost, flexible, inner)
-    return _extract_packed(admitted, alloc_idx, occupied, cap)
+    return _extract_packed(admitted, alloc_idx, occupied, cap, rounds)
 
 
 @functools.partial(jax.jit, static_argnames=("flexible", "inner"))
@@ -510,10 +524,11 @@ def _serve_batch_coupled(lat_ok, grid, price, cap, alive0, cost,
                          load, link_cap, incidence, group,
                          flexible: bool = True, inner: str = "jnp"):
     """Coupled serving fast path; additionally returns per-link loads."""
-    admitted, alloc_idx, occupied, used = _batch_solve_coupled(
+    admitted, alloc_idx, occupied, used, rounds = _batch_solve_coupled(
         lat_ok, grid, price, cap, alive0, cost, load, link_cap, incidence,
         group, flexible, inner)
-    packed, residual = _extract_packed(admitted, alloc_idx, occupied, cap)
+    packed, residual = _extract_packed(admitted, alloc_idx, occupied, cap,
+                                       rounds)
     return packed, residual, used
 
 
@@ -527,9 +542,10 @@ def solve_device_batch(dev: DeviceStack, *, flexible: bool = True,
     reads back one compact packed buffer. Returns a dict with ``admitted``
     (B, Tmax) bool, ``alloc_idx`` (B, Tmax) int (-1 where not admitted, as a
     mask-consumer convention: only ``admitted`` rows are meaningful),
-    ``residual`` (B, m) remaining capacity, and ``link_used`` (L,) admitted
-    shared-link load (zeros-length when uncoupled). Decisions are identical
-    to :func:`solve_greedy_batch` on the equivalently stacked host batch.
+    ``residual`` (B, m) remaining capacity, ``link_used`` (L,) admitted
+    shared-link load (zeros-length when uncoupled) and ``rounds``, the
+    admission rounds the device loop ran. Decisions are identical to
+    :func:`solve_greedy_batch` on the equivalently stacked host batch.
     """
     return unpack_device_batch(dispatch_device_batch(
         dev, flexible=flexible, inner=inner))
@@ -549,37 +565,68 @@ def dispatch_device_batch(dev: DeviceStack, *, flexible: bool = True,
     from ``DeviceStack.inputs()``, the double-buffer snapshot that stays
     valid while the serving loop scatters the next tick's rows.
     """
-    (lat_ok, grid, price, cap, alive0, cost,
-     link_load, link_cap, incidence, group) = dev.inputs()
-    if dev.coupled:
-        packed, residual, used = _serve_batch_coupled(
-            lat_ok, grid, price, cap, alive0, cost,
-            link_load, link_cap, incidence, group,
-            flexible=flexible, inner=inner)
-    else:
-        packed, residual = _serve_batch(
-            lat_ok, grid, price, cap, alive0, cost,
-            flexible=flexible, inner=inner)
-        used = np.zeros(0)
+    program = _serve_batch_coupled if dev.coupled else _serve_batch
+    with span("repro.solve.launch", B=dev.batch_size,
+              program=_module_name(program)):
+        (lat_ok, grid, price, cap, alive0, cost,
+         link_load, link_cap, incidence, group) = dev.inputs()
+        if dev.coupled:
+            packed, residual, used = _serve_batch_coupled(
+                lat_ok, grid, price, cap, alive0, cost,
+                link_load, link_cap, incidence, group,
+                flexible=flexible, inner=inner)
+        else:
+            packed, residual = _serve_batch(
+                lat_ok, grid, price, cap, alive0, cost,
+                flexible=flexible, inner=inner)
+            used = np.zeros(0)
     # capture the shape now: unpack must not depend on the (mutable) stack
     return packed, residual, used, dev.batch_size, dev.max_tasks
+
+
+def _module_name(program) -> str:
+    """The name of a jitted program's executions on the device trace (its
+    ``XLA Modules`` line, without the trailing id)."""
+    return f"jit_{program.__name__}"
+
+
+def _fetch(packed, residual, used, B: int):
+    """Wait for a launched solve, then copy its outputs to the host."""
+    with span("repro.solve.wait", B=B):
+        # request the decisions' host copy first, so that it starts when the
+        # device finishes, as a blocking np.asarray would have it start
+        packed.copy_to_host_async()
+        packed.block_until_ready()
+    with span("repro.solve.fetch", B=B):
+        return np.asarray(packed), np.asarray(residual), np.asarray(used)
+
+
+def _unpack_rows(packed: np.ndarray, tmax: int):
+    """Split packed decision rows into (admitted (R, tmax) bool, alloc_idx
+    (R, tmax) int64, rounds): see :func:`_extract_packed`."""
+    wt = -(-tmax // 32)
+    bits = packed[:, :wt].astype(np.uint32)
+    idx = np.arange(tmax)
+    admitted = (bits[:, idx // 32] >> (idx % 32).astype(np.uint32)) & 1 > 0
+    rounds = int(packed[:, -1].max(initial=0))
+    return admitted, packed[:, wt:wt + tmax].astype(np.int64), rounds
 
 
 def unpack_device_batch(dispatched: tuple) -> dict:
     """BLOCK on a :func:`dispatch_device_batch` handle and unpack it into
     the ``solve_device_batch`` result dict (the host synchronisation point)."""
     packed, residual, used, B, tmax = dispatched
-    packed = np.asarray(packed)[:B]      # drop inert pad_batch_to rows
-    wt = -(-tmax // 32)
-    bits = packed[:, :wt].astype(np.uint32)
-    idx = np.arange(tmax)
-    admitted = (bits[:, idx // 32] >> (idx % 32).astype(np.uint32)) & 1 > 0
-    return {
-        "admitted": admitted,
-        "alloc_idx": packed[:, wt:].astype(np.int64),
-        "residual": np.asarray(residual)[:B],
-        "link_used": np.asarray(used),
-    }
+    packed, residual, used = _fetch(packed, residual, used, B)
+    with span("repro.solve.unpack", B=B):
+        # drop inert pad_batch_to rows
+        admitted, alloc_idx, rounds = _unpack_rows(packed[:B], tmax)
+        return {
+            "admitted": admitted,
+            "alloc_idx": alloc_idx,
+            "residual": residual[:B],
+            "link_used": used,
+            "rounds": rounds,
+        }
 
 
 def solve_greedy_jax(inst: ProblemInstance, *, semantic: bool = True,
@@ -707,16 +754,16 @@ def _sharded_solve_fn(mesh, axis: str, flexible: bool, inner: str):
     """
     from jax.sharding import PartitionSpec as P
 
-    def body(lat_ok, grid, price, cap, alive0, cost, load, link_cap,
-             incidence, group):
-        admitted, alloc_idx, _, _ = _batch_solve_coupled(
+    def sharded_solve(lat_ok, grid, price, cap, alive0, cost, load, link_cap,
+                      incidence, group):
+        admitted, alloc_idx, _, _, _ = _batch_solve_coupled(
             lat_ok, grid, price, cap, alive0, cost, load, link_cap,
             incidence, group, flexible, inner)
         return admitted, alloc_idx
 
     cells, rep = P(axis), P()
     fn = jax.shard_map(
-        body, mesh=mesh,
+        sharded_solve, mesh=mesh,
         in_specs=(cells, rep, cells, cells, cells, rep, cells, rep, cells,
                   cells),
         out_specs=(cells, cells), check_vma=False)
@@ -731,24 +778,26 @@ def _sharded_serve_fn(mesh, axis: str, flexible: bool, inner: str):
     The sharded sibling of :func:`_serve_batch_coupled`: every shard solves
     its block of coupling groups and packs its own rows' decisions
     (``_extract_packed``), so the host reads back one small
-    ``(B', WT+Tmax)`` buffer instead of the full solution tables. The
+    ``(B', WT+Tmax+1)`` buffer instead of the full solution tables; each
+    shard's rows carry that shard's own round count. The
     per-shard link loads come back block-stacked — each link belongs to
     exactly one group, hence one shard, so summing the blocks reconstructs
     the global (L,) usage without a collective in the loop.
     """
     from jax.sharding import PartitionSpec as P
 
-    def body(lat_ok, grid, price, cap, alive0, cost, load, link_cap,
-             incidence, group):
-        admitted, alloc_idx, occupied, used = _batch_solve_coupled(
+    def sharded_serve(lat_ok, grid, price, cap, alive0, cost, load, link_cap,
+                      incidence, group):
+        admitted, alloc_idx, occupied, used, rounds = _batch_solve_coupled(
             lat_ok, grid, price, cap, alive0, cost, load, link_cap,
             incidence, group, flexible, inner)
-        packed, residual = _extract_packed(admitted, alloc_idx, occupied, cap)
+        packed, residual = _extract_packed(admitted, alloc_idx, occupied, cap,
+                                           rounds)
         return packed, residual, used
 
     cells, rep = P(axis), P()
     fn = jax.shard_map(
-        body, mesh=mesh,
+        sharded_serve, mesh=mesh,
         in_specs=(cells, rep, cells, cells, cells, rep, cells, rep, cells,
                   cells),
         out_specs=(cells, cells, cells), check_vma=False)
@@ -777,12 +826,14 @@ def dispatch_sharded_batch(shd: ShardedStack, *, flexible: bool = True,
     and returns a handle for :func:`unpack_sharded_batch`. The row map is
     captured at dispatch so a session replan cannot skew an in-flight tick.
     """
-    (lat_ok, grid, price, cap, alive0, cost,
-     link_load, link_cap, incidence, group) = shd.inputs()
-    packed, residual, used = _sharded_serve_fn(
-        shd.mesh, shd.axis, flexible, inner)(
-        lat_ok, grid, price, cap, alive0, cost,
-        link_load, link_cap, incidence, group)
+    program = _sharded_serve_fn(shd.mesh, shd.axis, flexible, inner)
+    with span("repro.solve.launch", B=shd.batch_size,
+              program=_module_name(program)):
+        (lat_ok, grid, price, cap, alive0, cost,
+         link_load, link_cap, incidence, group) = shd.inputs()
+        packed, residual, used = program(
+            lat_ok, grid, price, cap, alive0, cost,
+            link_load, link_cap, incidence, group)
     return (packed, residual, used, shd.batch_size, shd.max_tasks,
             shd.row_of, shd.num_shards, shd.coupled)
 
@@ -794,31 +845,29 @@ def unpack_sharded_batch(dispatched: tuple) -> dict:
     The packed buffer arrives in the padded shard layout; ``row_of`` gathers
     the live rows back so callers (the serving session's slot unpacker, the
     twin-engine tests) never see the plan. Inert padding rows never admit —
-    their decision rows are dropped.
+    their decision rows are dropped. ``rounds`` is the largest of the
+    shards' round counts: the slowest shard sets the solve's time.
     """
     (packed, residual, used, B, tmax, row_of, n_shards, coupled) = dispatched
-    packed = np.asarray(packed)
-    residual_p = np.asarray(residual)
-    wt = -(-tmax // 32)
-    bits = packed[:, :wt].astype(np.uint32)
-    idx = np.arange(tmax)
-    admitted_p = (bits[:, idx // 32] >> (idx % 32).astype(np.uint32)) & 1 > 0
-    alloc_p = packed[:, wt:].astype(np.int64)
-    live = row_of >= 0
-    admitted = np.zeros((B, tmax), bool)
-    alloc_idx = np.full((B, tmax), -1, np.int64)
-    out_residual = np.zeros((B, residual_p.shape[1]))
-    admitted[row_of[live]] = admitted_p[live]
-    alloc_idx[row_of[live]] = alloc_p[live]
-    out_residual[row_of[live]] = residual_p[live]
-    # per-shard (L,) blocks; disjoint link ownership makes the sum exact
-    used = np.asarray(used).reshape(n_shards, -1).sum(axis=0)
-    return {
-        "admitted": admitted,
-        "alloc_idx": alloc_idx,
-        "residual": out_residual,
-        "link_used": used if coupled else np.zeros(0),
-    }
+    packed, residual_p, used = _fetch(packed, residual, used, B)
+    with span("repro.solve.unpack", B=B):
+        admitted_p, alloc_p, rounds = _unpack_rows(packed, tmax)
+        live = row_of >= 0
+        admitted = np.zeros((B, tmax), bool)
+        alloc_idx = np.full((B, tmax), -1, np.int64)
+        out_residual = np.zeros((B, residual_p.shape[1]))
+        admitted[row_of[live]] = admitted_p[live]
+        alloc_idx[row_of[live]] = alloc_p[live]
+        out_residual[row_of[live]] = residual_p[live]
+        # per-shard (L,) blocks; disjoint link ownership makes the sum exact
+        used = used.reshape(n_shards, -1).sum(axis=0)
+        return {
+            "admitted": admitted,
+            "alloc_idx": alloc_idx,
+            "residual": out_residual,
+            "link_used": used if coupled else np.zeros(0),
+            "rounds": rounds,
+        }
 
 
 def solve_sharded_batch(shd: ShardedStack, *, flexible: bool = True,

@@ -23,6 +23,7 @@ from repro.core.greedy import (dispatch_device_batch, dispatch_sharded_batch,
                                unpack_device_batch, unpack_sharded_batch)
 from repro.core.sfesp import (DeviceStack, ShardedStack, empty_device_stack,
                               empty_sharded_stack, task_feasibility_rows)
+from repro.trace import span
 from .request import SliceRequest
 from .sdla import SDLA
 
@@ -177,6 +178,9 @@ class SESM:
         self.fresh_stacks = 0
         self.restacks = 0
         self.delta_rows = 0
+        # admission rounds the device ran, summed over the slot solves (the
+        # loop's trip count, read back with the packed decisions)
+        self.rounds = 0
         # fault-plane telemetry: session_rebuilds counts LIVE serve sessions
         # torn down by an invalidating change (batch/bucket/pools/coupling
         # identity/latency scale — first-ever builds are not rebuilds);
@@ -386,7 +390,8 @@ class SESM:
             if not live:
                 return out if wait else PendingSolve.ready(out)
             self.restacks += 1
-        self._sync_rows(sess, slot_rows)
+        with span("repro.sesm.sync_rows", B=B):
+            self._sync_rows(sess, slot_rows)
         if isinstance(sess.dev, ShardedStack):
             dispatched = dispatch_sharded_batch(sess.dev, flexible=flexible,
                                                 inner=self.inner)
@@ -396,9 +401,14 @@ class SESM:
                                                inner=self.inner)
             block = unpack_device_batch
         unpack = self._slot_unpacker(sess, slot_rows, out)
-        if wait:
-            return unpack(block(dispatched))
-        return PendingSolve(lambda: unpack(block(dispatched)))
+
+        def resolve():
+            res = block(dispatched)
+            self.rounds += res["rounds"]
+            with span("repro.sesm.decisions", B=B):
+                return unpack(res)
+
+        return resolve() if wait else PendingSolve(resolve)
 
     def ready_solve(self, request_sets, coupling=None,
                     pools=None) -> PendingSolve:

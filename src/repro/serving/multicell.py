@@ -102,6 +102,7 @@ from repro.core.events import (Arrival, CellFault, Departure, Event, Handover,
                                LinkScale, SemanticShift, Tick)
 from repro.core.latency import LatencyParams
 from repro.runtime.fault_tolerance import HeartbeatMonitor, StragglerMitigator
+from repro.trace import span
 from .admission import SESM, SliceDecision
 from .engine import CellRuntime, TaskRuntime, pinned_accuracy_at
 from .request import SliceRequest
@@ -440,6 +441,10 @@ class MultiCellEngine:
         Duplicate live request ids always raise — that is a caller bug, not
         an event race. Returns a summary dict of what the batch did.
         """
+        with span("repro.tick.ingest", tick=self.tick):
+            return self._ingest(events)
+
+    def _ingest(self, events) -> dict:
         s = dict(arrivals=0, placed=0, rehomed=0, lost=0, departures=0,
                  missing=0, handovers=0, handovers_skipped=0, failed=[],
                  recovered=[], moves={}, link_updates=0, semantic_shifts=0,
@@ -585,14 +590,16 @@ class MultiCellEngine:
         stay queued for the next round, and decisions for requests that
         departed meanwhile are dropped as stale at commit.
         """
-        self._pre_reslice()
-        rows, dirty = [], []
-        for cell in self.cells:
-            r, d = cell.sync_slots(consume=True)
-            rows.append(r)
-            dirty.append(d)
-        return self.sesm.solve_slots(rows, dirty, coupling=self.coupling,
-                                     pools=self.pools, wait=False)
+        with span("repro.tick.dispatch", tick=self.tick):
+            self._pre_reslice()
+            rows, dirty = [], []
+            with span("repro.tick.sync_slots", tick=self.tick):
+                for cell in self.cells:
+                    r, d = cell.sync_slots(consume=True)
+                    rows.append(r)
+                    dirty.append(d)
+            return self.sesm.solve_slots(rows, dirty, coupling=self.coupling,
+                                         pools=self.pools, wait=False)
 
     def reslice_commit(self, pending) -> list[list[SliceDecision]]:
         """Second half of :meth:`reslice`: await the dispatched solve's
@@ -601,10 +608,13 @@ class MultiCellEngine:
         ``preempt=True`` the awaited decisions first run the tier-aware
         preemption pass — which may replace them with a re-solve's — so the
         per-tier offered/admitted counters always see exactly ONE round."""
-        decisions = pending.wait()
-        if self.preempt:
-            decisions = self._preempt_pass(decisions)
-        return [cell.apply(ds) for cell, ds in zip(self.cells, decisions)]
+        with span("repro.tick.commit", tick=self.tick):
+            decisions = pending.wait()
+            if self.preempt:
+                decisions = self._preempt_pass(decisions)
+            with span("repro.tick.apply", tick=self.tick):
+                return [cell.apply(ds)
+                        for cell, ds in zip(self.cells, decisions)]
 
     def _preempt_pass(self, decisions: list[list[SliceDecision]]
                       ) -> list[list[SliceDecision]]:
@@ -752,8 +762,9 @@ class MultiCellEngine:
         """Per-cell metrics keyed by cell index (see CellRuntime.metrics),
         plus a ``"totals"`` entry aggregating the engine-wide SLA counters:
         retry-queue depth, drops/evictions/sheds (overall and per tier),
-        drain and fault-plane state, and the session-cache health counters
-        the degradation fast path is asserted on."""
+        drain and fault-plane state, the session-cache health counters the
+        degradation fast path is asserted on, and ``rounds``, the admission
+        rounds the device solves ran."""
         out: dict = {c: cell.metrics() for c, cell in enumerate(self.cells)}
 
         def merged(name: str) -> dict[int, int]:
@@ -780,6 +791,7 @@ class MultiCellEngine:
             link_updates=self.sesm.link_updates,
             semantic_updates=self.sesm.semantic_updates,
             session_rebuilds=self.sesm.session_rebuilds,
+            rounds=self.sesm.rounds,
             stragglers=sorted(self.stragglers.chronic()),
             offered_by_tier=merged("offered_by_tier"),
             admitted_by_tier=merged("admitted_by_tier"),
