@@ -171,6 +171,26 @@ def _inner_jnp(grid, price, cap, occupied, remaining, lat_ok, alive, cost,
     return G, best_a, has
 
 
+def _admit(admitted, alloc_idx, alive, tau, best, admit, keep):
+    """Admit task ``tau`` at allocation ``best`` where ``admit`` holds.
+
+    The per-round update of the (..., T) loop state as one lane-dense masked
+    elementwise pass: ``hit`` is the one-hot row of the admitted task, so no
+    indexed scatter (which the TPU applies one update at a time) is left in
+    the loop. ``tau``/``best``/``admit``/``keep`` carry the leading batch
+    shape, scalars for a single instance. The admitted task leaves the
+    candidate set, and an instance whose ``keep`` is False retires whole.
+    Returns ``(admitted, alloc_idx, alive, hit)``.
+    """
+    hit = (jnp.arange(admitted.shape[-1]) == tau[..., None]) \
+        & admit[..., None]
+    admitted = admitted | hit
+    alloc_idx = jnp.where(hit, best[..., None].astype(alloc_idx.dtype),
+                          alloc_idx)
+    alive = alive & ~hit & keep[..., None]
+    return admitted, alloc_idx, alive, hit
+
+
 def _round(state, lat_ok, grid, price, cap, cost, flexible: bool, inner_fn):
     """One admission round (Alg. 1 lines 8-19) as a masked state update.
 
@@ -191,11 +211,9 @@ def _round(state, lat_ok, grid, price, cap, cost, flexible: bool, inner_fn):
     G = jnp.where(alive, G, -jnp.inf)
     tau = jnp.argmax(G)
     admit_now = jnp.any(alive)
-    admitted = admitted.at[tau].set(admitted[tau] | admit_now)
-    alloc_idx = jnp.where(
-        admit_now, alloc_idx.at[tau].set(best_a[tau]), alloc_idx)
+    admitted, alloc_idx, alive, _ = _admit(admitted, alloc_idx, alive, tau,
+                                           best_a[tau], admit_now, admit_now)
     occupied = occupied + jnp.where(admit_now, grid[best_a[tau]], 0.0)
-    alive = alive.at[tau].set(False)
     return admitted, alloc_idx, occupied, alive
 
 
@@ -336,7 +354,6 @@ def _batch_solve(lat_ok, grid, price, cap, alive0, cost,
     """
     B, tmax, A = lat_ok.shape
     m = grid.shape[1]
-    bidx = jnp.arange(B)
 
     if not flexible:
         def body(state):
@@ -361,13 +378,11 @@ def _batch_solve(lat_ok, grid, price, cap, alive0, cost,
         admitted, alloc_idx, occupied, alive, rounds = state
         v, tau, best_a = round_fn(occupied, alive)
         admit = v > -jnp.inf
-        admitted = admitted.at[bidx, tau].set(admitted[bidx, tau] | admit)
-        alloc_idx = alloc_idx.at[bidx, tau].set(
-            jnp.where(admit, best_a.astype(jnp.int32), alloc_idx[bidx, tau]))
-        occupied = occupied + jnp.where(admit[:, None], grid[best_a], 0.0)
         # the admitted task leaves the candidate set; a round with nothing
         # feasible retires the whole instance (the oracle's line-15 mass drop)
-        alive = alive.at[bidx, tau].set(False) & admit[:, None]
+        admitted, alloc_idx, alive, _ = _admit(admitted, alloc_idx, alive,
+                                               tau, best_a, admit, admit)
+        occupied = occupied + jnp.where(admit[:, None], grid[best_a], 0.0)
         return admitted, alloc_idx, occupied, alive, rounds + 1
 
     def cond(state):
@@ -445,20 +460,20 @@ def _batch_solve_coupled(lat_ok, grid, price, cap, alive0, cost,
         headroom = jnp.where(inc_b, rem[None, :], jnp.inf).min(-1)   # (B,)
         link_ok = load <= headroom[:, None] + 1e-9                   # (B, T)
         v, tau, best_a = round_fn(occupied, alive & link_ok)
-        gmax = jax.ops.segment_max(v, group, num_segments=B)
-        att = (v > -jnp.inf) & (v == gmax[group])
-        first = jax.ops.segment_min(jnp.where(att, bidx, B), group,
-                                    num_segments=B)
-        admit = att & (bidx == first[group])
-        admitted = admitted.at[bidx, tau].set(admitted[bidx, tau] | admit)
-        alloc_idx = alloc_idx.at[bidx, tau].set(
-            jnp.where(admit, best_a.astype(jnp.int32), alloc_idx[bidx, tau]))
+        # group reductions over a (B, B) same-group mask: elementwise, where
+        # segment max/min would scatter one cell at a time
+        same = group[:, None] == group[None, :]
+        gmax = jnp.where(same, v[None, :], -jnp.inf).max(-1)
+        att = (v > -jnp.inf) & (v == gmax)
+        first = jnp.where(same & att[None, :], bidx[None, :], B).min(-1)
+        admit = att & (bidx == first)
+        # a cell with nothing feasible retires; losers stay for next round
+        admitted, alloc_idx, alive, hit = _admit(
+            admitted, alloc_idx, alive, tau, best_a, admit, v > -jnp.inf)
         occupied = occupied + jnp.where(admit[:, None], grid[best_a], 0.0)
-        used = used + (jnp.where(admit, load[bidx, tau], 0.0)[:, None]
+        # the admitted task's load: one non-zero per row, so the sum is exact
+        used = used + (jnp.where(hit, load, 0.0).sum(-1)[:, None]
                        * inc_f).sum(axis=0)
-        alive = jnp.where(admit[:, None], alive.at[bidx, tau].set(False),
-                          alive)
-        alive = alive & (v > -jnp.inf)[:, None]
         return admitted, alloc_idx, occupied, alive, used, rounds + 1
 
     def cond(state):
@@ -746,11 +761,10 @@ def _sharded_solve_fn(mesh, axis: str, flexible: bool, inner: str):
     """Jitted shard_map entry of the metro solve, cached per (mesh, mode).
 
     Each shard runs the UNMODIFIED coupled batch core on its block of the
-    group-major batch: local group ids keep every ``segment_max`` /
-    ``segment_min`` reduction shard-local, so no collective appears in the
-    loop and each shard's ``while_loop`` converges independently — a
-    congested group never serializes the fleet (per-group round
-    convergence, no global barrier).
+    group-major batch: local group ids keep every group max / min reduction
+    shard-local, so no collective appears in the loop and each shard's
+    ``while_loop`` converges independently — a congested group never
+    serializes the fleet (per-group round convergence, no global barrier).
     """
     from jax.sharding import PartitionSpec as P
 

@@ -1,11 +1,13 @@
 """Batched sweep engine vs the per-instance numpy oracle (Alg. 1)."""
 import dataclasses
+from unittest import mock
 
 import numpy as np
 import pytest
 
-from repro.core import (build_instance, next_pow2, restack, scenarios,
-                        solve_greedy, solve_greedy_batch, solve_greedy_jax,
+from repro.core import (build_instance, device_stack, greedy, next_pow2,
+                        restack, scenarios, solve_device_batch, solve_greedy,
+                        solve_greedy_batch, solve_greedy_jax,
                         solve_greedy_many, stack_instances)
 
 
@@ -23,21 +25,92 @@ def _random_instances():
     return insts
 
 
-def _assert_matches_oracle(insts, *, semantic=True, flexible=True):
-    sols = solve_greedy_batch(insts, semantic=semantic, flexible=flexible)
+def _oracle_rounds(inst, ref, iterations, *, semantic, flexible):
+    """Rounds the batched loop spends on ``inst``, from the numpy oracle.
+
+    MinRes drops an infeasible candidate in the round it becomes so, as
+    Alg. 1 does: the oracle's own ``iterations``. The flexible round drops
+    one only when its instance has nothing feasible left: one round per
+    admission, plus one retiring round if a candidate was never admitted.
+    """
+    if not flexible:
+        return iterations
+    lat, z_idx = greedy._select_tables(inst, semantic)
+    alive0 = (z_idx >= 0) & (lat <= inst.tasks.max_latency[:, None]).any(1)
+    return int(ref.admitted.sum()) + int((alive0 & ~ref.admitted).any())
+
+
+def _assert_matches_oracle(insts, *, semantic=True, flexible=True,
+                           tmax=None, pad_batch_to=None):
+    stacked = stack_instances(insts, tmax=tmax)
+    sols = solve_greedy_batch(stacked, semantic=semantic, flexible=flexible,
+                              pad_batch_to=pad_batch_to)
     assert len(sols) == len(insts)
+    rounds = 0
     for inst, sol in zip(insts, sols):
-        ref = solve_greedy(inst, semantic=semantic, flexible=flexible)
+        # one primal_gradient call per iteration of the oracle's loop
+        with mock.patch.object(greedy, "primal_gradient",
+                               wraps=greedy.primal_gradient) as pg:
+            ref = solve_greedy(inst, semantic=semantic, flexible=flexible)
         assert sol.admitted.shape == (inst.num_tasks,)
         assert (sol.admitted == ref.admitted).all()
         assert np.allclose(sol.alloc, ref.alloc)
         assert np.allclose(sol.z, ref.z)
         assert sol.objective == pytest.approx(ref.objective)
         assert (sol.satisfied == ref.satisfied).all()
+        rounds = max(rounds, _oracle_rounds(inst, ref, pg.call_count,
+                                            semantic=semantic,
+                                            flexible=flexible))
+    # the loop runs to the slowest instance; inert pad rows add no round
+    dev = device_stack(stacked, semantic=semantic, pad_batch_to=pad_batch_to)
+    assert solve_device_batch(dev, flexible=flexible)["rounds"] == rounds
 
 
-def test_batched_matches_oracle_randomized():
-    _assert_matches_oracle(_random_instances())
+def _odd_tmax_instances():
+    """Task counts 13 and 5: Tmax 13, not a multiple of 8."""
+    pool = scenarios.numerical_pool(2)
+    return [build_instance(pool, scenarios.numerical_tasks(n, "low", "high",
+                                                           seed=n))
+            for n in (13, 5)]
+
+
+def _mass_drop_instances():
+    """The middle instance's pool fits no allocation (0.5 RBG, one level
+    is 1): its every candidate retires in round 1 while the others admit."""
+    pool = scenarios.numerical_pool(2)
+    tiny = dataclasses.replace(pool, capacity=np.array([0.5, 20.0]))
+    return [build_instance(p, scenarios.numerical_tasks(9, "low", "high",
+                                                        seed=s))
+            for s, p in enumerate((pool, tiny, pool))]
+
+
+def _no_alive_instances():
+    """An instance with no candidate (accuracy out of reach) beside
+    feasible ones, the device batch padded with inert rows."""
+    pool = scenarios.numerical_pool(2)
+    tasks = scenarios.numerical_tasks(7, "med", "high", seed=4)
+    hopeless = dataclasses.replace(tasks, min_accuracy=np.full(7, 0.99))
+    return [build_instance(pool, t) for t in (
+        hopeless, scenarios.numerical_tasks(11, "low", "high", seed=5),
+        tasks)]
+
+
+@pytest.mark.parametrize("case,kw", [
+    ("random", {}),
+    ("odd_tmax", {}),
+    ("odd_tmax", {"tmax": 21}),
+    ("mass_drop", {}),
+    ("no_alive", {"pad_batch_to": 8}),
+])
+def test_batched_matches_oracle_randomized(case, kw):
+    insts = {"random": _random_instances, "odd_tmax": _odd_tmax_instances,
+             "mass_drop": _mass_drop_instances,
+             "no_alive": _no_alive_instances}[case]()
+    _assert_matches_oracle(insts, **kw)
+    if case == "mass_drop":
+        alive0 = np.asarray(device_stack(stack_instances(insts)).alive0)
+        assert alive0[1].any()
+        assert solve_greedy_batch(insts)[1].num_allocated == 0
 
 
 @pytest.mark.parametrize("semantic", [True, False])

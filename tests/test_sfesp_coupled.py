@@ -4,8 +4,9 @@ import dataclasses
 import numpy as np
 import pytest
 
-from repro.core import (CouplingSpec, build_instance, merge_coupling,
-                        scenarios, semantics, solve_coupled_ref, solve_greedy,
+from repro.core import (CouplingSpec, build_instance, device_stack,
+                        merge_coupling, scenarios, semantics,
+                        solve_coupled_ref, solve_device_batch, solve_greedy,
                         solve_greedy_batch, solve_greedy_many, stack_instances,
                         restack, task_link_load)
 
@@ -43,9 +44,35 @@ def _assert_matches_ref(insts, **kw):
     return sols
 
 
-def test_coupled_matches_oracle_randomized():
-    for seed in range(4):
-        insts, cap, inc = _coupled_instances(seed=seed)
+def _shared_link_instances(tiny_cell=None):
+    """Cells 0-2 share one link whose budget never binds: one coupling
+    group, which admits one task a round, so a cell that loses a round keeps
+    its candidates for the next. Cell 3 is link-free; Tmax is 13. The pool
+    of ``tiny_cell`` fits no allocation (0.5 RBG, one level is 1): its
+    every candidate retires in round 1 while its group goes on admitting."""
+    pools = scenarios.multi_cell_pools(4, seed=8)
+    if tiny_cell is not None:
+        pools[tiny_cell] = dataclasses.replace(
+            pools[tiny_cell],
+            capacity=np.array([0.5, pools[tiny_cell].capacity[1]]))
+    cap = np.array([1e9])
+    inc = np.array([[True], [True], [True], [False]])
+    insts = [build_instance(pool, scenarios.numerical_tasks(
+        n, "low", "high", seed=40 + c), coupling=CouplingSpec(cap,
+                                                             inc[c:c + 1]))
+        for c, (pool, n) in enumerate(zip(pools, (7, 13, 10, 6)))]
+    return insts, cap, inc
+
+
+@pytest.mark.parametrize("case", ["random", "loser_stays_alive",
+                                  "mass_drop"])
+def test_coupled_matches_oracle_randomized(case):
+    if case == "random":
+        batches = [_coupled_instances(seed=seed) for seed in range(4)]
+    else:
+        batches = [_shared_link_instances(
+            tiny_cell=1 if case == "mass_drop" else None)]
+    for insts, cap, inc in batches:
         sols = _assert_matches_ref(insts)
         # shared-link budgets hold for the admitted set
         for link in range(len(cap)):
@@ -53,6 +80,21 @@ def test_coupled_matches_oracle_randomized():
                 float((task_link_load(i) * s.admitted).sum())
                 for i, s, on in zip(insts, sols, inc[:, link]) if on)
             assert used <= cap[link] + 1e-6
+    if case == "random":
+        return
+    group_admits = [s.num_allocated for s in sols[:3]]
+    dev = device_stack(stack_instances(insts))
+    if case == "mass_drop":
+        assert np.asarray(dev.alive0)[1].any()
+        assert group_admits[1] == 0 and sum(group_admits) > 0
+    else:
+        # two cells of one group admitted: the later one lost a round
+        # with its candidates alive and contended again
+        assert sum(n > 0 for n in group_admits) >= 2
+    # the group admits one task a round, then at most one retiring round
+    rounds = solve_device_batch(dev)["rounds"]
+    assert sum(group_admits) <= rounds <= max(
+        sum(group_admits), sols[3].num_allocated) + 1
 
 
 @pytest.mark.parametrize("semantic", [True, False])

@@ -19,8 +19,8 @@ import jax.numpy as jnp
 from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from repro.core.greedy import (_serve_batch_coupled, _sharded_serve_fn,
-                               clear_sharded_caches)
+from repro.core.greedy import (_serve_batch, _serve_batch_coupled,
+                               _sharded_serve_fn, clear_sharded_caches)
 from repro.data.pipeline import FrameStream
 from repro.kernels.pg import pg
 from repro.kernels.resize import ref as resize_ref
@@ -99,6 +99,25 @@ def test_serve_batch_coupled_compiles_at_metro_shapes(one_chip, inner):
         functools.partial(_serve_batch_coupled, flexible=True, inner=inner),
         *_coupled_args(spec, 256, 15, 300, 2, 32))
     assert compiled.memory_analysis().argument_size_in_bytes < 16 * 2**20
+
+
+@pytest.mark.parametrize("b,t,a,m,flexible", [
+    (960, 50, 1280, 4, True), (960, 50, 1280, 4, False),   # paper4res sweep
+    (960, 50, 300, 2, True), (960, 50, 300, 2, False),     # paper2res sweep
+    (256, 15, 300, 2, None)])                              # coupled metro
+def test_admission_loop_holds_no_scatter(one_chip, b, t, a, m, flexible):
+    """The admission round updates its (B, Tmax) state by one-hot masks: no
+    scatter, which the TPU applies one update at a time, is left in the
+    serve programs (``flexible=None`` is the coupled program, flexible)."""
+    spec = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    args = _coupled_args(spec, b, t, a, m, 32)
+    if flexible is None:
+        fn = functools.partial(_serve_batch_coupled, flexible=True,
+                               inner="jnp")
+    else:
+        fn, args = functools.partial(_serve_batch, flexible=flexible,
+                                     inner="jnp"), args[:6]
+    assert " scatter(" not in _compile(fn, *args).as_text()
 
 
 def test_sharded_serve_compiles_on_four_chips(topo):
