@@ -3,8 +3,9 @@ against the plain reference (``bench/reference.py``) on the same inputs.
 
 Serving cells: for each sampled tick, every cell's decision list (the
 candidates the engine solved, in its order) is rebuilt in the reference from
-the requests' own parameters, solved jointly per backhaul domain, and each
-task's admission flag and allocation compared. Besides, every decided
+the requests' own parameters, solved jointly over each coupling group (the
+connected components of the cell-link graph) against each cell's own pool,
+and each task's admission flag and allocation compared. Besides, every decided
 request must be one the traffic sent to that cell and had not withdrawn
 before the tick (``stale``), and every arrival sent just before a tick must
 be decided in it (``missing``).
@@ -22,7 +23,7 @@ largest relative gap it looked at (``reference.Cell.look``).
 Controls (``bench/control.py``; never part of a benchmark run) are put in
 the program's place and compared the same way: the reference with its
 gradient in bfloat16, and the reference with one of the configuration's
-guarantees broken (serving: the backhaul budgets left out, each cell solved
+guarantees broken (serving: the link budgets left out, each cell solved
 alone; sweep: admitted allocations not charged to the pool).
 """
 
@@ -57,36 +58,39 @@ def _ties(cells, into: dict | None = None) -> dict:
 def reference_tick(dep, decisions, dtype=np.float64, coupled: bool = True,
                    hint=None):
     """Reference (admitted, allocation index) per cell for one tick's
-    candidate lists, and its tie readings on ``hint``;
-    ``coupled=False`` drops the backhaul budgets."""
+    candidate lists, solved jointly over each coupling group in cell order,
+    and its tie readings on ``hint``; ``coupled=False`` drops the link
+    budgets, each cell solved alone."""
     out = [None] * dep.n_cells
     ties = None
-    for dom in range(len(dep.link_budget)):
-        cells_idx = np.flatnonzero(dep.domain == dom)
-        cells = [ref.Cell(_cell_tasks(decisions[c], dep.grid),
-                          dep.price[c], dep.capacity[c], dep.grid, dtype,
-                          hint=hint[c] if hint else frozenset())
-                 for c in cells_idx]
+    for group in dep.groups():
+        cells = []
+        for c in group:
+            grid = dep.pool(c).grid
+            cells.append(ref.Cell(_cell_tasks(decisions[c], grid),
+                                  dep.price[c], dep.capacity[c], grid, dtype,
+                                  hint=hint[c] if hint else frozenset()))
         if coupled:
-            inc = np.zeros((len(cells_idx), len(dep.link_budget)), bool)
-            inc[:, dom] = True
-            ref.solve_group(cells, dep.link_budget, inc)
+            ref.solve_group(cells, dep.link_budget, dep.incidence[group])
         else:
             for cell in cells:
                 ref.solve_cell(cell)
-        for c, cell in zip(cells_idx, cells):
+        for c, cell in zip(group, cells):
             out[c] = (cell.admitted, cell.alloc)
         ties = _ties(cells, ties)
     return out, ties
 
 
 def decision_table(dep, decisions):
-    """The engine's decisions as (admitted, allocation index) per cell."""
-    index = {tuple(row): i for i, row in enumerate(dep.grid.tolist())}
+    """The engine's decisions as (admitted, allocation index) per cell, each
+    allocation indexed in its cell's own grid."""
+    index = [{tuple(row): i for i, row in enumerate(p.grid.tolist())}
+             for p in dep.pools]
     out = []
-    for ds in decisions:
+    for c, ds in enumerate(decisions):
+        pool, at = dep.pool(c), index[dep.pool_of[c]]
         adm = np.array([d.admitted for d in ds], bool)
-        alloc = np.array([index[tuple(d.alloc[n] for n in dep.names)]
+        alloc = np.array([at[tuple(d.alloc[n] for n in pool.names)]
                           if d.admitted else -1 for d in ds], np.int64)
         out.append((adm, alloc))
     return out
@@ -100,13 +104,24 @@ def mismatches(got, expected) -> int:
     return bad
 
 
-def compare_tick(dep, decisions, got=None) -> tuple[int, dict]:
-    """(mismatches, tie readings) of a decision table ``got`` (the engine's
-    own by default) against the reference on ``decisions``' candidate
-    lists."""
+def compare_tick(dep, decisions, got=None) -> tuple[int, dict, list]:
+    """(mismatches, tie readings, the reference's table) of a decision table
+    ``got`` (the engine's own by default) against the reference on
+    ``decisions``' candidate lists."""
     got = decision_table(dep, decisions) if got is None else got
-    expected, ties = reference_tick(dep, decisions, hint=hints(got))
-    return mismatches(got, expected), ties
+    hint = hints(got)
+    expected, ties = reference_tick(dep, decisions, hint=hint)
+    return mismatches(got, expected), ties, expected
+
+
+def groups_bound(dep, decisions, expected) -> int:
+    """The coupling groups of one tick in which the link budgets decide
+    something: Algorithm 1 without them (each cell alone) differs from
+    ``expected``, the reference's table with them."""
+    loose, _ = reference_tick(dep, decisions, coupled=False,
+                              hint=hints(expected))
+    return sum(mismatches([expected[c] for c in g], [loose[c] for c in g]) > 0
+               for g in dep.groups())
 
 
 def membership(decisions, tick: int, sent: dict, withdrawn: dict,

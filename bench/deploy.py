@@ -1,13 +1,23 @@
 """Deployments built from a configuration file, for the program and for the
 reference alike.
 
-A metro deployment is ``n_cells`` cells in ``n_domains`` contiguous backhaul
-domains, one shared aggregation link per domain with a budget of
-``link_budget_per_cell`` x the domain's cell count. Cell pools scatter the
-paper's numerical pool by +-``capacity_spread`` from ``pool_seed`` (fixed by
-the configuration, not by the run's seed: the deployment stays put while the
-traffic varies). A sweep deployment is one pool and a grid of what-if
-instances drawn from the run's seed.
+A serving deployment is cells, pools and links:
+
+* ``pools``: named pools, each with ``names``, ``capacity`` and ``levels``
+  (its allocation grid is every combination of the levels);
+* ``cells``: contiguous runs ``[pool name, count]``, in cell order;
+* ``links``: tiers of shared links, each ``{"name", "cells_per_link",
+  "budget_per_cell"}``: cell c lies on link c // ``cells_per_link`` of the
+  tier, and a link's budget is ``budget_per_cell`` x its cell count. A cell
+  lies on one link of every tier, so on as many links as there are tiers.
+
+Each cell's capacities scatter its pool's by +-``capacity_spread``, drawn
+in cell order from ``pool_seed`` (fixed by the configuration, not by the
+run's seed: the deployment stays put while the traffic varies); prices are
+1 / capacity.
+
+A sweep deployment is one pool and a grid of what-if instances drawn from
+the run's seed.
 """
 
 from __future__ import annotations
@@ -20,15 +30,23 @@ from bench import reference as ref
 
 
 @dataclasses.dataclass
-class Metro:
-    """The deployment as plain arrays (what the reference reads)."""
+class Pool:
+    """A pool's resources and its enumerated allocation grid."""
 
     names: tuple[str, ...]
     levels: tuple[np.ndarray, ...]
     grid: np.ndarray                 # (A, m)
-    capacity: np.ndarray             # (C, m)
-    price: np.ndarray                # (C, m)
-    domain: np.ndarray               # (C,) link of each cell
+
+
+@dataclasses.dataclass
+class Metro:
+    """The deployment as plain arrays (what the reference reads)."""
+
+    pools: tuple[Pool, ...]
+    pool_of: np.ndarray              # (C,) index into ``pools``
+    capacity: list[np.ndarray]       # (m,) per cell
+    price: list[np.ndarray]          # (m,) per cell
+    incidence: np.ndarray            # (C, L) bool: the links of each cell
     link_budget: np.ndarray          # (L,)
     apps: list[tuple[str, float, float]]
     max_latency_s: float
@@ -36,7 +54,28 @@ class Metro:
 
     @property
     def n_cells(self) -> int:
-        return len(self.domain)
+        return len(self.pool_of)
+
+    def pool(self, c: int) -> Pool:
+        return self.pools[self.pool_of[c]]
+
+    def groups(self) -> list[np.ndarray]:
+        """The coupling groups: connected components of the cell-link
+        graph, each as its cells in ascending order, ordered by their first
+        cell."""
+        parent = np.arange(self.n_cells)
+
+        def find(a: int) -> int:
+            while parent[a] != a:
+                a = parent[a]
+            return a
+
+        for users in (np.flatnonzero(col) for col in self.incidence.T):
+            for other in users[1:]:
+                ra, rb = find(int(users[0])), find(int(other))
+                parent[max(ra, rb)] = min(ra, rb)
+        roots = np.array([find(c) for c in range(self.n_cells)])
+        return [np.flatnonzero(roots == r) for r in np.unique(roots)]
 
 
 def pool_levels(pool: dict) -> tuple[np.ndarray, ...]:
@@ -44,22 +83,35 @@ def pool_levels(pool: dict) -> tuple[np.ndarray, ...]:
 
 
 def metro(cfg: dict) -> Metro:
-    n, d = int(cfg["n_cells"]), int(cfg["n_domains"])
-    pool = cfg["pool"]
-    base = np.asarray(pool["capacity"], np.float64)
+    names = list(cfg["pools"])
+    pools = []
+    for p in cfg["pools"].values():
+        levels = pool_levels(p)
+        pools.append(Pool(tuple(p["names"]), levels,
+                          ref.allocation_grid(levels)))
+    pool_of = np.concatenate([np.full(int(n), names.index(name))
+                              for name, n in cfg["cells"]])
+    n = len(pool_of)
     spread = float(cfg["capacity_spread"])
     rng = np.random.default_rng(int(cfg["pool_seed"]))
-    cap = np.empty((n, len(base)))
+    cap = []
     for c in range(n):
+        base = np.asarray(cfg["pools"][names[pool_of[c]]]["capacity"],
+                          np.float64)
         scale = rng.uniform(1.0 - spread, 1.0 + spread, size=len(base))
-        cap[c] = np.maximum(np.round(base * scale), 2.0)
-    domain = (np.arange(n) * d) // n
-    budget = np.bincount(domain, minlength=d) * float(
-        cfg["link_budget_per_cell"])
-    levels = pool_levels(pool)
-    return Metro(names=tuple(pool["names"]), levels=levels,
-                 grid=ref.allocation_grid(levels), capacity=cap,
-                 price=1.0 / cap, domain=domain, link_budget=budget,
+        cap.append(np.maximum(np.round(base * scale), 2.0))
+    columns, budgets = [], []
+    for tier in cfg["links"]:
+        k = int(tier["cells_per_link"])
+        link, n_links = np.arange(n) // k, -(-n // k)
+        for j in range(n_links):
+            columns.append(link == j)
+            budgets.append(float(tier["budget_per_cell"])
+                           * int(columns[-1].sum()))
+    return Metro(pools=tuple(pools), pool_of=pool_of, capacity=cap,
+                 price=[1.0 / c for c in cap],
+                 incidence=np.stack(columns, axis=1),
+                 link_budget=np.asarray(budgets, np.float64),
                  apps=[tuple(a) for a in cfg["apps"]],
                  max_latency_s=float(cfg["max_latency_s"]),
                  max_retries=int(cfg["max_retries"]))
@@ -72,12 +124,12 @@ def engine(dep: Metro, chips: int):
     from repro.launch.mesh import make_cells_mesh
     from repro.serving import MultiCellEngine
 
-    pools = [ResourcePool(names=dep.names, capacity=dep.capacity[c].copy(),
-                          price=dep.price[c].copy(), levels=dep.levels)
+    pools = [ResourcePool(names=dep.pool(c).names,
+                          capacity=dep.capacity[c].copy(),
+                          price=dep.price[c].copy(),
+                          levels=dep.pool(c).levels)
              for c in range(dep.n_cells)]
-    inc = np.zeros((dep.n_cells, len(dep.link_budget)), bool)
-    inc[np.arange(dep.n_cells), dep.domain] = True
-    spec = CouplingSpec(dep.link_budget.copy(), inc)
+    spec = CouplingSpec(dep.link_budget.copy(), dep.incidence.copy())
     return MultiCellEngine(pools, coupling=spec, mesh=make_cells_mesh(chips),
                            max_retries=dep.max_retries)
 

@@ -3,13 +3,17 @@ it into the events (serving mixes) or instances (sweep mixes) of a run.
 
 Mix keys, by ``loop``:
 
-* ``closed`` — a closed churn loop. ``live_per_cell`` requests seed every
-  cell (the deployment's apps in turn); each tick ``churn_share`` x cells,
-  drawn from the seed, each lose their oldest request the engine still holds
-  (a ``Departure``) and gain one arrival; the apps of a tick's arrivals are
-  the deployment's apps in equal shares, in an order drawn from the seed, so
-  every seed offers the same work. The next tick's events are drawn after
-  the previous tick commits.
+* ``closed`` — a closed loop of held requests, one per user (a full
+  buffer: every user always has a request). ``live_per_cell`` requests seed
+  every cell, the deployment's apps in equal shares in an order drawn from
+  the seed. A request the engine drops (rejected ``max_retries`` times) is
+  asked again by its user at the next tick, with the same app, so every cell
+  always holds ``live_per_cell`` requests. Besides, each tick
+  ``churn_share`` x cells, drawn from the seed, each lose their oldest
+  request still held (a ``Departure``) and gain one arrival; the apps of a
+  tick's arrivals are the deployment's apps in equal shares, in an order
+  drawn from the seed, so every seed offers the same work. The next tick's
+  events are drawn after the previous tick commits.
 * ``open`` — an open loop. Poisson arrivals at ``rate_per_s`` over the
   whole deployment; each picks its cell uniformly and its app uniformly, and
   departs after an exponential holding time of mean
@@ -46,6 +50,8 @@ class Closed:
         self.warm_ticks = int(mix["warm_ticks"])
         self.fifo: list[list[int]] = [[] for _ in range(dep.n_cells)]
         self.sent: dict[int, int] = {}           # rid -> cell
+        self.app_of: dict[int, int] = {}         # rid -> app, while held
+        self.drops = [0] * dep.n_cells           # the engine's, last seen
 
     def _arrive(self, cell: int, app: int):
         from repro.core.events import Arrival
@@ -53,29 +59,40 @@ class Closed:
         req = deploy.request(self.dep, app)
         self.fifo[cell].append(req.request_id)
         self.sent[req.request_id] = cell
+        self.app_of[req.request_id] = app
         return Arrival(req, cell)
 
     def initial(self) -> list:
         n_apps = len(self.dep.apps)
-        return [self._arrive(c, j % n_apps) for c in range(self.dep.n_cells)
-                for j in range(self.live_per_cell)]
+        share = np.arange(self.live_per_cell) % n_apps
+        return [self._arrive(c, a) for c in range(self.dep.n_cells)
+                for a in self.rng.permutation(share).tolist()]
 
     def tick(self, engine) -> list:
-        """One tick's events: ``k`` cells each lose their oldest request the
-        engine still holds and gain a new arrival."""
+        """One tick's events: ``k`` cells each lose their oldest request
+        and gain a new arrival; every request the engine dropped since the
+        last tick is asked again."""
         from repro.core.events import Departure
 
+        again = []
+        for c, cell in enumerate(engine.cells):
+            if cell.drops == self.drops[c]:
+                continue
+            self.drops[c] = cell.drops
+            fifo = self.fifo[c]
+            again += [(c, r) for r in fifo if engine.locate(r) != c]
+            self.fifo[c] = [r for r in fifo if engine.locate(r) == c]
         cells = self.rng.choice(self.dep.n_cells, size=self.k, replace=False)
         apps = self.rng.permutation(np.arange(self.k) % len(self.dep.apps))
         events = []
         for c, a in zip(cells.tolist(), apps.tolist()):
-            fifo = self.fifo[c]
-            while fifo and engine.locate(fifo[0]) != c:
-                fifo.pop(0)                   # dropped by the engine
-            if fifo:
-                events.append(Departure(fifo.pop(0), c))
+            if self.fifo[c]:
+                rid = self.fifo[c].pop(0)
+                del self.app_of[rid]
+                events.append(Departure(rid, c))
             events.append(self._arrive(c, a))
-        return events
+        return events + [self._arrive(c, self.app_of.pop(r))
+                         for c, r in again]
 
 
 class Open:

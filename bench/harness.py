@@ -244,21 +244,30 @@ def merge_ties(into: dict, ties: dict, prefix: str = "") -> dict:
 
 
 def serving_checks(dep, kept, sent, withdrawn) -> tuple[dict, dict]:
-    """The compared numbers with their limits, and the tie readings of the
-    comparison (``bench/check.py``)."""
+    """The compared numbers with their limits, and the readings of the
+    comparison (``bench/check.py``): its ties, and the share of sampled
+    ticks and of sampled (tick, coupling group) pairs in which the link
+    budgets decided something."""
     mism = stale = missing = 0
-    ties = merge_ties({}, {"ties_followed": 0, "tie_gap_max": 0.0})
+    bound = bound_ticks = 0
+    readings = merge_ties({}, {"ties_followed": 0, "tie_gap_max": 0.0})
     for tick, decisions, fresh in kept:
-        m, t = check.compare_tick(dep, decisions)
+        m, t, expected = check.compare_tick(dep, decisions)
         mism += m
-        merge_ties(ties, t)
+        merge_ties(readings, t)
+        g = check.groups_bound(dep, decisions, expected)
+        bound += g
+        bound_ticks += g > 0
         s, m = check.membership(decisions, tick, sent, withdrawn, fresh)
         stale += s
         missing += m
+    n = max(len(kept), 1)
+    readings["links_bound_ticks"] = bound_ticks / n
+    readings["links_bound_groups"] = bound / (n * len(dep.groups()))
     return ({"mismatched_decisions": [mism, 0],
              "stale_decisions": [stale, 0], "missing_arrivals": [missing, 0],
              "ticks_compared": [len(kept), ">=1"]},
-            ties)
+            readings)
 
 
 def serving_controls(dep, kept) -> dict:
@@ -273,7 +282,7 @@ def serving_controls(dep, kept) -> dict:
                                                   ml_dtypes.bfloat16)[0]),
                 ("budgets_left_out", check.reference_tick(
                     dep, decisions, coupled=False)[0])):
-            m, t = check.compare_tick(dep, decisions, ctl)
+            m, t, _ = check.compare_tick(dep, decisions, ctl)
             out[key] += m
             merge_ties(out, t, f"{key}.")
     return out
@@ -532,6 +541,7 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t0: float,
     setup_s = win.start - t0
     peak = memory_peak(devices) if devices is not None else 0
     controls = None
+    mark = time.perf_counter()
     if loop == "sweep":
         del devs, stacked, insts
         win.checks, ties, controls = sweep_checks(cell, batches, last, kept,
@@ -544,6 +554,7 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t0: float,
                                           withdrawn)
         if control:
             controls = serving_controls(dep, x["kept"])
+    check_s = time.perf_counter() - mark     # controls included, if asked
     device = device_entry(devices, peak)
     metrics: dict[str, dict] = {}
     breakdown = None
@@ -572,7 +583,7 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, t0: float,
     if breakdown is not None:
         out["breakdown"] = breakdown
     info = dict(setup_parts=parts, compiles_in_window=compiles,
-                window_s=win.seconds, **counters, **ties)
+                window_s=win.seconds, check_s=check_s, **counters, **ties)
     if loop == "open":
         info["withdrawn"] = win.extra["withdrawn"]
     out["info"] = info
@@ -596,8 +607,9 @@ def e2e_values(loop: str, win: Window, setup_s: float,
                counters: dict) -> dict:
     vals = {"setup_s": setup_s}
     if loop == "closed":
-        vals["tick_ms"] = win.seconds / len(win.ticks) * 1e3
-        vals["tick_p95_ms"] = percentile(win.ticks, 95) * 1e3
+        vals["tick_ms"] = win.seconds / counters["ticks"] * 1e3
+        vals["tick_p50_ms"] = percentile(win.ticks, 50) * 1e3
+        vals["tick_p99_ms"] = percentile(win.ticks, 99) * 1e3
     elif loop == "open":
         lat = list(win.extra["latencies"])
         # an arrival never decided missed every limit: it counts at the

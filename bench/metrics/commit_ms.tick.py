@@ -1,0 +1,7 @@
+"""Serving commit: seconds under the span `bench.commit` per tick, ms."""
+from bench.layer import per_tick
+
+
+def read(r):
+    s = r.span_s("bench.commit")
+    return per_tick(r, None if s is None else s * 1e3)

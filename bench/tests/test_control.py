@@ -9,6 +9,7 @@ from cells import run_tiny
 
 
 @pytest.mark.parametrize("name,key", [
+    ("imt_du_macro.full_buffer", "budgets_left_out"),
     ("metro.churn", "budgets_left_out"),
     ("metro.open", "budgets_left_out"),
     ("paper4res.sweep", "capacity_not_charged"),

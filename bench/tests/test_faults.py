@@ -5,7 +5,7 @@ batch left out."""
 import numpy as np
 import pytest
 
-from cells import run_tiny
+from cells import run_tiny, tiny_cell
 
 
 def _flip_one(res):
@@ -36,11 +36,13 @@ def _sweep(monkeypatch, alter):
                         lambda dev, **kw: alter(orig(dev, **kw)))
 
 
-@pytest.mark.parametrize("name", ["metro.churn", "metro.open",
-                                  "paper4res.sweep", "paper2res.sweep"])
+@pytest.mark.parametrize("name", ["imt_du_macro.full_buffer", "metro.churn",
+                                  "metro.open", "paper4res.sweep",
+                                  "paper2res.sweep"])
 @pytest.mark.parametrize("fault", [_flip_one, _half])
 def test_fault_turns_correct_false(monkeypatch, name, fault):
-    patch = _serving if name.startswith("metro") else _sweep
+    serving = tiny_cell(name).traffic["loop"] != "sweep"
+    patch = _serving if serving else _sweep
     patch(monkeypatch, fault)
     out = run_tiny(name)
     assert not out["correct"]

@@ -1,6 +1,6 @@
-"""The trace reduction, on hand-made planes and on a small trace recorded on
-a TPU v5e (a few ticks of the 256-cell serving loop,
-``data/small_trace.xplane.pb``)."""
+"""The trace reduction and the serving tick's readers, on hand-made planes
+and on a small trace recorded on a TPU v5e (a few ticks of the 256-cell
+serving loop, ``data/small_trace.xplane.pb``)."""
 
 import pathlib
 
@@ -49,3 +49,30 @@ def test_recorded_trace():
     for span in ("bench.ingest", "bench.dispatch", "bench.commit"):
         assert r["spans"][span][0] > 0
     assert r["device_ops"] and r["idle_gaps"]
+
+
+TICK_READERS = ["ingest_ms.tick", "dispatch_ms.tick", "commit_ms.tick",
+                "device_ms.tick", "device_idle_pct.tick"]
+
+
+@pytest.mark.parametrize("name", TICK_READERS)
+def test_tick_readers_on_recorded_trace(name):
+    from bench import harness
+
+    r = trace.reduce_planes(trace.read_planes(
+        str(DATA / "small_trace.xplane.pb")), 1)
+    ticks = r["spans"]["bench.dispatch"][0]
+    reading = harness.Reading("serving", r, {"ticks": ticks}, {})
+    value = harness.load_reader(name)(reading)
+    if name.startswith("device_idle"):
+        assert value == pytest.approx(100 * (1 - r["busy_s"] / r["window_s"]))
+    elif name.startswith("device_ms"):
+        assert value == pytest.approx(r["busy_s"] * 1e3 / ticks)
+    else:
+        span = "bench." + name.split("_")[0]
+        assert value == pytest.approx(r["spans"][span][1] * 1e3 / ticks)
+    assert 0 < value < 100
+    # a window without the span, or without ticks, reads nothing per tick
+    empty = harness.Reading("serving", dict(r, spans={}), {"ticks": 0}, {})
+    if not name.startswith("device_idle"):
+        assert harness.load_reader(name)(empty) is None
