@@ -1,7 +1,8 @@
 """SF-ESP core: the paper's contribution (semantic + flexible edge slicing)."""
 
 from .types import (CouplingSpec, ProblemInstance, ResourcePool, Solution,
-                    StackedInstances, TaskSet, make_allocation_grid)
+                    StackedInstances, TaskSet, UnionGrid,
+                    make_allocation_grid, union_allocation_grid)
 from .sfesp import (DeviceStack, ShardedStack, TaskRows, build_instance,
                     check_solution, default_z_grid, device_stack,
                     device_stack_sharded, empty_device_stack,
@@ -23,8 +24,8 @@ from . import latency, scenarios, semantics
 __all__ = [
     "CouplingSpec", "DEFAULT_MODEL", "DeviceStack", "ProblemInstance",
     "ResourcePool", "SemanticModel", "ShardedStack", "Solution",
-    "StackedInstances", "TaskRows", "TaskSet",
-    "make_allocation_grid",
+    "StackedInstances", "TaskRows", "TaskSet", "UnionGrid",
+    "make_allocation_grid", "union_allocation_grid",
     "build_instance", "check_solution", "default_z_grid", "device_stack",
     "device_stack_sharded", "empty_device_stack", "empty_sharded_stack",
     "group_major_order",

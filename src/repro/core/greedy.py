@@ -404,15 +404,31 @@ def _greedy_jax_batch(lat_ok, grid, price, cap, alive0, cost,
     return admitted, alloc_idx, occupied
 
 
+def _pair_add(a_hi, a_lo, b_hi, b_lo):
+    """``a + b`` for numbers held as float32 pairs ``hi + lo``: Knuth's
+    two-sum of the high parts, their rounding error carried with the low
+    parts, renormalised so that ``lo`` is below half an ulp of ``hi``."""
+    s = a_hi + b_hi
+    bb = s - a_hi
+    err = (a_hi - (s - bb)) + (b_hi - bb)
+    lo = err + a_lo + b_lo
+    hi = s + lo
+    return hi, lo - (hi - s)
+
+
 def _batch_solve_coupled(lat_ok, grid, price, cap, alive0, cost,
                          load, link_cap, incidence, group,
                          flexible: bool = True, inner: str = "jnp"):
     """Coupled variant of :func:`_batch_solve`: cells sharing backhaul
     links admit JOINTLY. Also returns the per-link admitted load ``used``.
 
-    Extra inputs: ``load`` (B, Tmax) per-task shared-link load, ``link_cap``
-    (L,), ``incidence`` (B, L) bool and ``group`` (B,) int — the connected
-    components of the cell–link graph (``CouplingSpec.groups``). Each round:
+    Extra inputs: ``load`` (B, Tmax, 2) per-task shared-link load and
+    ``link_cap`` (L, 2) link budgets, each a float32 pair ``hi + lo``
+    (``sfesp.split_f32``), ``incidence`` (B, L) bool and ``group`` (B,) int
+    — the connected components of the cell–link graph
+    (``CouplingSpec.groups``). Link use is summed and compared on the pairs
+    (``_pair_add``), so a load fits its link's remaining budget where it
+    would in float64. Each round:
 
       1. per-cell candidate masks additionally require the task's load to fit
          the REMAINING budget of every link its cell traverses (folded into
@@ -436,7 +452,8 @@ def _batch_solve_coupled(lat_ok, grid, price, cap, alive0, cost,
     m = grid.shape[1]
     bidx = jnp.arange(B)
     inc_b = incidence.astype(bool)                          # (B, L)
-    inc_f = incidence.astype(grid.dtype)
+    inc_f = incidence.astype(load.dtype)
+    finite = jnp.isfinite(link_cap[:, 0])                   # (L,)
 
     if flexible:
         lat_bits = _pack_bits(lat_ok)
@@ -456,9 +473,19 @@ def _batch_solve_coupled(lat_ok, grid, price, cap, alive0, cost,
 
     def body(state):
         admitted, alloc_idx, occupied, alive, used, rounds = state
-        rem = link_cap - used                                        # (L,)
-        headroom = jnp.where(inc_b, rem[None, :], jnp.inf).min(-1)   # (B,)
-        link_ok = load <= headroom[:, None] + 1e-9                   # (B, T)
+        # remaining budget per link, and per cell its tightest link's
+        rem_hi, rem_lo = _pair_add(link_cap[:, 0], link_cap[:, 1],
+                                   -used[:, 0], -used[:, 1])         # (L,)
+        rem_hi = jnp.where(finite, rem_hi, jnp.inf)
+        rem_lo = jnp.where(finite, rem_lo, 0.0)
+        head_hi = jnp.where(inc_b, rem_hi[None, :], jnp.inf).min(-1)  # (B,)
+        head_lo = jnp.where(inc_b & (rem_hi[None, :] == head_hi[:, None]),
+                            rem_lo[None, :], jnp.inf).min(-1)
+        head_lo = jnp.where(jnp.isinf(head_hi), 0.0, head_lo)
+        # load <= headroom + 1e-9: the hi difference is exact where it is
+        # small, so the sign is float64's
+        link_ok = ((head_hi[:, None] - load[..., 0])
+                   + (head_lo[:, None] - load[..., 1]) + 1e-9) >= 0  # (B, T)
         v, tau, best_a = round_fn(occupied, alive & link_ok)
         # group reductions over a (B, B) same-group mask: elementwise, where
         # segment max/min would scatter one cell at a time
@@ -471,9 +498,12 @@ def _batch_solve_coupled(lat_ok, grid, price, cap, alive0, cost,
         admitted, alloc_idx, alive, hit = _admit(
             admitted, alloc_idx, alive, tau, best_a, admit, v > -jnp.inf)
         occupied = occupied + jnp.where(admit[:, None], grid[best_a], 0.0)
-        # the admitted task's load: one non-zero per row, so the sum is exact
-        used = used + (jnp.where(hit, load, 0.0).sum(-1)[:, None]
-                       * inc_f).sum(axis=0)
+        # the admitted task's load: one non-zero per row and per link, so
+        # both sums are exact
+        add = (jnp.where(hit[..., None], load, 0.0).sum(1)[:, None, :]
+               * inc_f[:, :, None]).sum(axis=0)                      # (L, 2)
+        used = jnp.stack(_pair_add(used[:, 0], used[:, 1],
+                                   add[:, 0], add[:, 1]), axis=-1)
         return admitted, alloc_idx, occupied, alive, used, rounds + 1
 
     def cond(state):
@@ -481,10 +511,10 @@ def _batch_solve_coupled(lat_ok, grid, price, cap, alive0, cost,
 
     init = (jnp.zeros((B, tmax), bool), jnp.full((B, tmax), -1, jnp.int32),
             jnp.zeros((B, m), grid.dtype), alive0,
-            jnp.zeros(link_cap.shape, grid.dtype), jnp.int32(0))
+            jnp.zeros(link_cap.shape, load.dtype), jnp.int32(0))
     admitted, alloc_idx, occupied, _, used, rounds = \
         jax.lax.while_loop(cond, body, init)
-    return admitted, alloc_idx, occupied, used, rounds
+    return admitted, alloc_idx, occupied, used.sum(-1), rounds
 
 
 @functools.partial(jax.jit, static_argnames=("flexible", "inner"))
@@ -581,8 +611,8 @@ def dispatch_device_batch(dev: DeviceStack, *, flexible: bool = True,
     valid while the serving loop scatters the next tick's rows.
     """
     program = _serve_batch_coupled if dev.coupled else _serve_batch
-    with span("repro.solve.launch", B=dev.batch_size,
-              program=_module_name(program)):
+    with span("repro.solve.launch", B=dev.batch_size, A=dev.grid.shape[0],
+              grids=dev.grids, program=_module_name(program)):
         (lat_ok, grid, price, cap, alive0, cost,
          link_load, link_cap, incidence, group) = dev.inputs()
         if dev.coupled:
@@ -841,8 +871,8 @@ def dispatch_sharded_batch(shd: ShardedStack, *, flexible: bool = True,
     captured at dispatch so a session replan cannot skew an in-flight tick.
     """
     program = _sharded_serve_fn(shd.mesh, shd.axis, flexible, inner)
-    with span("repro.solve.launch", B=shd.batch_size,
-              program=_module_name(program)):
+    with span("repro.solve.launch", B=shd.batch_size, A=shd.grid.shape[0],
+              grids=shd.grids, program=_module_name(program)):
         (lat_ok, grid, price, cap, alive0, cost,
          link_load, link_cap, incidence, group) = shd.inputs()
         packed, residual, used = program(
@@ -944,30 +974,36 @@ def solve_greedy_many(insts, *, semantic: bool = True, flexible: bool = True,
                       inner: str = "jnp") -> list[Solution]:
     """Grid-grouped sweep dispatcher: batch-solve instances with MIXED grids.
 
-    :func:`stack_instances` requires one shared allocation grid;
-    heterogeneous multi-cell traces (per-cell ``pool.levels``) previously
-    fell back to a per-instance Python loop. This front door groups the
-    instances by grid identity and solves each group through the batched
-    engine, padding ``Tmax`` and the device batch to power-of-two buckets so
-    repeated sweeps with fluctuating task counts / group sizes land on a
-    handful of cached device programs instead of recompiling.
+    Heterogeneous multi-cell traces (per-cell ``pool.levels``) would pad
+    every instance onto one union grid if stacked whole. This front door
+    groups the instances by grid identity and solves each group through the
+    batched engine, padding ``Tmax`` and the device batch to power-of-two
+    buckets so repeated sweeps with fluctuating task counts / group sizes
+    land on a handful of cached device programs instead of recompiling.
 
     Returns one :class:`Solution` per instance, in input order. Decisions are
     exactly those of :func:`solve_greedy_batch` on each group (hence the same
     f32 tie-break caveat vs the numpy oracle). Backhaul-coupled instances are
-    solved jointly within their grid group; cells of one coupling group MUST
-    therefore share an allocation grid (a link whose users were split across
-    grid groups would have its budget double-counted — rejected up front).
+    solved jointly: grid groups joined by a shared link stack together onto
+    their union grid (:func:`~repro.core.sfesp.stack_instances`), so a link
+    whose users sit on different grids is charged once, by one solve.
     """
     insts = list(insts)
-    groups: dict[bytes, list[int]] = {}
-    keys: list[bytes] = []
-    for i, inst in enumerate(insts):
+    keys: dict[bytes, int] = {}
+    key_of = []
+    for inst in insts:
         key = np.ascontiguousarray(inst.grid).tobytes() \
             + repr(inst.grid.shape).encode()
-        keys.append(key)
-        groups.setdefault(key, []).append(i)
-    link_users: dict[tuple, set] = {}
+        key_of.append(keys.setdefault(key, len(keys)))
+    # grid groups joined by a link (transitively) solve as one stack
+    parent = list(range(len(keys)))
+
+    def find(k: int) -> int:
+        while parent[k] != k:
+            k = parent[k]
+        return k
+
+    first_user: dict[tuple, int] = {}
     for i, inst in enumerate(insts):
         spec = inst.coupling
         if spec is None:
@@ -976,11 +1012,12 @@ def solve_greedy_many(insts, *, semantic: bool = True, flexible: bool = True,
             # link sets are identified by capacity-array identity, matching
             # the merge_coupling contract
             lid = (id(spec.link_capacity), int(link))
-            link_users.setdefault(lid, set()).add(keys[i])
-    if any(len(g) > 1 for g in link_users.values()):
-        raise ValueError(
-            "backhaul-coupled cells must share one allocation grid "
-            "(identical pool.levels); a shared link cannot span grid groups")
+            a, b = find(key_of[i]), find(first_user.setdefault(lid,
+                                                               key_of[i]))
+            parent[max(a, b)] = min(a, b)
+    groups: dict[int, list[int]] = {}
+    for i, k in enumerate(key_of):
+        groups.setdefault(find(k), []).append(i)
     out: list[Solution | None] = [None] * len(insts)
     for idxs in groups.values():
         sub = [insts[i] for i in idxs]

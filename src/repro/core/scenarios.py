@@ -321,7 +321,8 @@ def multi_cell_trace(n_cells: int, horizon: int, *, m: int = 2,
     Returns ``horizon * n_cells`` instances (cell-adjacent within a step) and
     matching ``{"step", "cell"}`` metadata. With the default ``n_grids=1``
     the full trace stacks into one batch (shared level grid); ``n_grids > 1``
-    yields per-cell allocation grids — solve via ``solve_greedy_many``.
+    yields per-cell allocation grids — solve via ``solve_greedy_many``, or
+    stack whole onto the union grid.
 
     ``shared_backhaul`` models the transport between the cells and the edge
     cluster: the cells of each step share ONE backhaul link with that budget
@@ -329,13 +330,8 @@ def multi_cell_trace(n_cells: int, horizon: int, *, m: int = 2,
     problems, so the trace's :class:`~repro.core.types.CouplingSpec` carries
     one link PER STEP (L = horizon) and instance (step, cell) loads only its
     step's link — the whole trace still solves as one coupled batch, with one
-    coupling group per step.
+    coupling group per step (mixing grids when ``n_grids > 1``).
     """
-    if shared_backhaul is not None and n_grids != 1:
-        raise ValueError(
-            "shared_backhaul requires n_grids=1: cells coupled through a "
-            "link must share one allocation grid (no solver path accepts a "
-            "link spanning grid groups)")
     pools = multi_cell_pools(n_cells, m=m, seed=seed, n_grids=n_grids)
     link_cap = None if shared_backhaul is None \
         else np.full(horizon, float(shared_backhaul))

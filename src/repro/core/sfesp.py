@@ -39,14 +39,16 @@ import jax.numpy as jnp
 from . import latency as lat_mod
 from . import semantics
 from .types import (CouplingSpec, ProblemInstance, ResourcePool, Solution,
-                    StackedInstances, TaskSet, make_allocation_grid)
+                    StackedInstances, TaskSet, UnionGrid,
+                    make_allocation_grid, union_allocation_grid)
 
 __all__ = ["build_instance", "check_solution", "objective_value",
            "default_z_grid", "stack_instances", "restack", "next_pow2",
            "task_link_load", "merge_coupling", "lexicographic_cost",
            "group_major_order", "group_offsets_of",
            "TaskRows", "task_feasibility_rows",
-           "DeviceStack", "device_stack", "empty_device_stack",
+           "DeviceStack", "device_stack", "empty_device_stack", "stack_grid",
+           "split_f32",
            "ShardedStack", "shard_plan", "device_stack_sharded",
            "empty_sharded_stack"]
 
@@ -90,6 +92,26 @@ class TaskRows:
     load: np.ndarray     # (T,) — shared-link load b_τ·λ_τ·z*_τ
 
 
+def _latency_rows(lat_params: lat_mod.LatencyParams, tasks: TaskSet,
+                  z: np.ndarray, grid: np.ndarray
+                  ) -> tuple[np.ndarray, np.ndarray]:
+    """(T, A) latency table and its deadline test, each distinct task row
+    evaluated once: a row depends only on (b, λ, P₁, z*, L), and requests of
+    one application repeat them, so a tick re-admitting a thousand requests
+    of ten applications evaluates ten rows. The function is elementwise, so
+    a row is the same bits whichever rows are evaluated with it."""
+    key = np.stack([tasks.bits_per_job, tasks.jobs_per_sec,
+                    tasks.gpu_time_per_job, z, tasks.max_latency], axis=1)
+    uniq, inv = np.unique(key, axis=0, return_inverse=True)
+    if len(uniq) == len(key):
+        lat = lat_mod.latency_table(lat_params, tasks, z, grid)
+        return lat, lat <= tasks.max_latency[:, None]
+    lat = lat_mod.latency(lat_params, uniq[:, 0, None], uniq[:, 1, None],
+                          uniq[:, 2, None], uniq[:, 3, None], grid[None, :, :])
+    inv = inv.reshape(-1)
+    return lat[inv], (lat <= uniq[:, 4, None])[inv]
+
+
 def task_feasibility_rows(tasks: TaskSet, z_grid: np.ndarray,
                           grid: np.ndarray,
                           lat_params: lat_mod.LatencyParams | None = None, *,
@@ -111,8 +133,7 @@ def task_feasibility_rows(tasks: TaskSet, z_grid: np.ndarray,
     z_idx = model.min_z_for_accuracy(app, tasks.min_accuracy, z_grid)
     # pruned tasks get z=1 rows; they are excluded by z_idx == -1 anyway
     z = _z_star_of(z_grid, z_idx)
-    lat = lat_mod.latency_table(lat_params, tasks, z, grid)
-    lat_ok = lat <= tasks.max_latency[:, None]
+    lat, lat_ok = _latency_rows(lat_params, tasks, z, grid)
     alive = (z_idx >= 0) & lat_ok.any(axis=1)
     load = tasks.bits_per_job * tasks.jobs_per_sec * z
     return TaskRows(z_idx=z_idx, z_star=z, lat=lat, lat_ok=lat_ok,
@@ -228,14 +249,25 @@ def group_offsets_of(coupling: CouplingSpec | None,
     return np.r_[starts, batch_size].astype(np.int64)
 
 
-def _check_shared_grid(insts: Sequence[ProblemInstance], grid: np.ndarray,
-                       what: str):
-    for inst in insts:
-        if not np.array_equal(inst.grid, grid):
-            raise ValueError(
-                f"all {what} instances must share one allocation grid "
-                "(identical pool.levels); use solve_greedy_many to dispatch "
-                "mixed-grid sets per grid group")
+def stack_grid(insts: Sequence[ProblemInstance]) -> UnionGrid:
+    """The grid a batch stacks onto: the instances' shared grid, or the
+    union of their grids with each row's own allocations masked."""
+    grid = insts[0].grid
+    if all(np.array_equal(inst.grid, grid) for inst in insts[1:]):
+        return UnionGrid(grid, None)
+    return union_allocation_grid([inst.pool for inst in insts])
+
+
+def _on_grid(table: np.ndarray, mask: np.ndarray | None,
+            fill=np.inf) -> np.ndarray:
+    """``table`` (T, A_own), one column per allocation of a cell's own grid,
+    laid onto the union grid (T, A) of ``mask``, ``fill`` elsewhere; the
+    table itself where the cell is on the shared grid (``mask`` None)."""
+    if mask is None:
+        return table
+    out = np.full((table.shape[0], mask.shape[0]), fill, table.dtype)
+    out[:, mask] = table
+    return out
 
 
 def _shared_model(insts: Sequence[ProblemInstance], what: str):
@@ -274,8 +306,12 @@ def _fill_stacked(st: StackedInstances, insts: tuple[ProblemInstance, ...],
     def cat(get):
         return np.concatenate([np.asarray(get(i)) for i in insts], axis=0)
 
-    st.lat[rows, cols] = cat(lambda i: i.lat)
-    st.lat_agnostic[rows, cols] = cat(lambda i: i.lat_agnostic)
+    masks = [None] * B if st.cell_mask is None else st.cell_mask
+    # rows of a union grid: +inf (never within L_c) off the cell's own grid
+    st.lat[rows, cols] = np.concatenate(
+        [_on_grid(i.lat, k) for i, k in zip(insts, masks)], axis=0)
+    st.lat_agnostic[rows, cols] = np.concatenate(
+        [_on_grid(i.lat_agnostic, k) for i, k in zip(insts, masks)], axis=0)
     st.z_star_idx[rows, cols] = cat(lambda i: i.z_star_idx)
     st.z_star_idx_agnostic[rows, cols] = cat(lambda i: i.z_star_idx_agnostic)
     st.z_star[rows, cols] = cat(lambda i: _z_star_of(i.z_grid, i.z_star_idx))
@@ -300,7 +336,9 @@ def stack_instances(insts: Sequence[ProblemInstance], *,
                     group_major: bool = False) -> StackedInstances:
     """Stack instances into one padded batch for the sweep engine.
 
-    Instances must share the allocation grid (identical ``pool.levels``);
+    Instances on one allocation grid (identical ``pool.levels``) stack onto
+    it; instances on different grids (same resources) stack onto their
+    union grid, each row masked to its own allocations (``cell_mask``);
     capacities/prices may differ per instance (multi-cell pools). Tasks are
     padded to ``Tmax`` with never-feasible rows (lat=+inf, z*_idx=-1) so the
     batched solver's masked rounds ignore them. ``tmax`` overrides the
@@ -322,8 +360,8 @@ def stack_instances(insts: Sequence[ProblemInstance], *,
     if group_major:
         perm = group_major_order(insts)
         insts = tuple(insts[i] for i in perm)
-    grid = insts[0].grid
-    _check_shared_grid(insts[1:], grid, "stacked")
+    union = stack_grid(insts)
+    grid = union.grid
     B = len(insts)
     A, m = grid.shape
     n_tasks = np.array([inst.num_tasks for inst in insts], np.int64)
@@ -348,6 +386,7 @@ def stack_instances(insts: Sequence[ProblemInstance], *,
         link_load_agnostic=np.zeros((B, tmax)),
         coupling=merge_coupling(insts),
         semantics=_shared_model(insts, "stacked"),
+        cell_mask=union.mask,
     )
     if group_major:
         st = dataclasses.replace(
@@ -363,8 +402,8 @@ def restack(stacked: StackedInstances,
     The closed-loop trace case: every step re-solves an admission problem
     whose grid and batch size are fixed while tasks and capacities change;
     reallocating the (B, Tmax, A) latency tables each step dominates the
-    host-side cost. Contract: same allocation grid, same batch size, and
-    every new instance's task count must fit the existing ``Tmax``
+    host-side cost. Contract: same (union) allocation grid, same batch
+    size, and every new instance's task count must fit the existing ``Tmax``
     (otherwise a ValueError asks the caller to re-stack at a larger bucket).
 
     The returned :class:`StackedInstances` SHARES the buffers of ``stacked``,
@@ -382,7 +421,11 @@ def restack(stacked: StackedInstances,
     if stacked.group_major:
         perm = group_major_order(insts)
         insts = tuple(insts[i] for i in perm)
-    _check_shared_grid(insts, stacked.grid, "restacked")
+    union = stack_grid(insts)
+    if not np.array_equal(union.grid, stacked.grid):
+        raise ValueError(
+            "restacked instances must stack onto the batch's allocation "
+            "grid; re-stack instead")
     n_tasks = np.array([inst.num_tasks for inst in insts], np.int64)
     if n_tasks.max(initial=0) > stacked.max_tasks:
         raise ValueError(
@@ -408,7 +451,7 @@ def restack(stacked: StackedInstances,
         perm=perm,
         group_offsets=(group_offsets_of(coupling, len(insts))
                        if stacked.group_major else None),
-        semantics=_shared_model(insts, "restacked"))
+        semantics=_shared_model(insts, "restacked"), cell_mask=union.mask)
     _fill_stacked(st, insts, n_tasks)
     return st
 
@@ -461,8 +504,23 @@ def _scatter_rows(lat_ok, alive0, link_load, b_idx, t_idx,
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _scatter_budgets(link_cap, new_cap):
-    """Overwrite the (L,) device link-budget buffer in place (donated)."""
+    """Overwrite the (L, 2) device link-budget buffer in place (donated)."""
     return link_cap.at[:].set(new_cap)
+
+
+def split_f32(x) -> np.ndarray:
+    """``x`` as float32 pairs (..., 2): ``hi`` the float32 nearest ``x``,
+    ``lo`` the float32 nearest what ``hi`` leaves out (0 where ``x`` is not
+    finite). The device's link accounting adds and compares such pairs
+    (``greedy._batch_solve_coupled``), so a link budget binds where it would
+    in float64: a plain float32 sum of a few dozen loads errs by more than
+    the margin by which a load can miss its link's remaining budget."""
+    x = np.asarray(x, np.float64)
+    hi = x.astype(np.float32)
+    lo = np.zeros_like(hi)
+    fin = np.isfinite(x)
+    lo[fin] = (x[fin] - hi[fin].astype(np.float64)).astype(np.float32)
+    return np.stack([hi, lo], axis=-1)
 
 
 @dataclasses.dataclass
@@ -489,12 +547,14 @@ class DeviceStack:
     capacity: jax.Array              # (B', m)
     lat_ok: jax.Array                # (B', Tmax, A) bool
     alive0: jax.Array                # (B', Tmax) bool
-    link_load: jax.Array             # (B', Tmax) — zeros when uncoupled
-    link_cap: jax.Array | None       # (L,)
+    link_load: jax.Array             # (B', Tmax, 2) f32 pairs (split_f32),
+    # zeros when uncoupled
+    link_cap: jax.Array | None       # (L, 2) f32 pairs
     incidence: jax.Array | None      # (B', L) bool
     group: jax.Array | None          # (B',) int
     semantic: bool
     batch_size: int                  # real B (B' may include inert padding)
+    grids: int = 1                   # distinct cell grids on ``grid``
     scatter_calls: int = 0
     rows_scattered: int = 0
     budget_updates: int = 0
@@ -532,8 +592,9 @@ class DeviceStack:
         """Delta-scatter changed task rows into the device buffers.
 
         ``b_idx``/``t_idx`` (D,) address the rows; ``lat_ok_rows`` (D, A)
-        bool, ``alive_rows`` (D,) bool, ``load_rows`` (D,) float (defaults to
-        zeros for uncoupled batches). Row counts are padded to a power-of-two
+        bool, ``alive_rows`` (D,) bool, ``load_rows`` (D,) float64 (defaults
+        to zeros for uncoupled batches; uploaded as :func:`split_f32`
+        pairs). Row counts are padded to a power-of-two
         bucket with out-of-range ``t_idx`` entries, which the jitted scatter
         drops — so arrival/departure bursts of any size reuse a handful of
         compiled programs. A ``t_idx`` >= ``max_tasks`` is a bucket overflow:
@@ -556,8 +617,7 @@ class DeviceStack:
             raise ValueError(
                 f"cell index {int(b_idx.max())} outside the stacked batch "
                 f"of {nrows} rows")
-        if load_rows is None:
-            load_rows = np.zeros(d)
+        load_rows = split_f32(np.zeros(d) if load_rows is None else load_rows)
         bucket = next_pow2(d)
         pad = bucket - d
         if pad:
@@ -568,13 +628,14 @@ class DeviceStack:
             lat_ok_rows = np.concatenate(
                 [lat_ok_rows, np.zeros((pad,) + lat_ok_rows.shape[1:], bool)])
             alive_rows = np.concatenate([alive_rows, np.zeros(pad, bool)])
-            load_rows = np.concatenate([load_rows, np.zeros(pad)])
+            load_rows = np.concatenate([load_rows,
+                                        np.zeros((pad, 2), np.float32)])
         self.lat_ok, self.alive0, self.link_load = _scatter_rows(
             self.lat_ok, self.alive0, self.link_load,
             jnp.asarray(b_idx), jnp.asarray(t_idx),
             jnp.asarray(np.asarray(lat_ok_rows, bool)),
             jnp.asarray(np.asarray(alive_rows, bool)),
-            jnp.asarray(np.asarray(load_rows, np.float64)))
+            jnp.asarray(load_rows))
         self.scatter_calls += 1
         self.rows_scattered += d
 
@@ -615,12 +676,13 @@ class DeviceStack:
                 "this stack is uncoupled (no link budgets to update); "
                 "introducing links is a topology change — rebuild")
         new = np.asarray(budgets, np.float64)
-        if new.shape != self.link_cap.shape:
+        if new.shape != self.link_cap.shape[:1]:
             raise ValueError(
                 f"budget shape {new.shape} != device link set "
-                f"{self.link_cap.shape}; changing the link set is a "
+                f"{self.link_cap.shape[:1]}; changing the link set is a "
                 "topology change — rebuild the stack")
-        self.link_cap = _scatter_budgets(self.link_cap, jnp.asarray(new))
+        self.link_cap = _scatter_budgets(self.link_cap,
+                                         jnp.asarray(split_f32(new)))
         self.budget_updates += 1
 
 
@@ -686,8 +748,8 @@ def device_stack(stacked: StackedInstances, *, semantic: bool = True,
             inc = np.concatenate([inc, np.zeros((pad, inc.shape[1]), bool)])
     if coupled:
         group = CouplingSpec(coupling.link_capacity, inc).groups()
-        link = (jnp.asarray(coupling.link_capacity), jnp.asarray(inc),
-                jnp.asarray(group))
+        link = (jnp.asarray(split_f32(coupling.link_capacity)),
+                jnp.asarray(inc), jnp.asarray(group))
     else:
         link = (None, None, None)
     dev = DeviceStack(
@@ -695,9 +757,9 @@ def device_stack(stacked: StackedInstances, *, semantic: bool = True,
         cost=jnp.asarray(lexicographic_cost(stacked.grid)),
         price=jnp.asarray(price), capacity=jnp.asarray(cap),
         lat_ok=jnp.asarray(lat_ok), alive0=jnp.asarray(alive0),
-        link_load=jnp.asarray(load),
+        link_load=jnp.asarray(split_f32(load)),
         link_cap=link[0], incidence=link[1], group=link[2],
-        semantic=bool(semantic), batch_size=B,
+        semantic=bool(semantic), batch_size=B, grids=stacked.grids,
     )
     cache[key] = dev
     return dev
@@ -706,13 +768,14 @@ def device_stack(stacked: StackedInstances, *, semantic: bool = True,
 def empty_device_stack(grid: np.ndarray, price: np.ndarray,
                        capacity: np.ndarray, tmax: int, *,
                        coupling: CouplingSpec | None = None,
-                       semantic: bool = True) -> DeviceStack:
+                       semantic: bool = True, grids: int = 1) -> DeviceStack:
     """A device stack of CLEARED rows (never feasible, never alive).
 
     The serving fast path allocates one per (batch, Tmax-bucket) and scatters
     live task rows in as they arrive/change (:meth:`DeviceStack.update_rows`);
     cells' prices/capacities (B, m) and the coupling topology are the
-    invariants uploaded here, once.
+    invariants uploaded here, once. ``grid`` may be the union of ``grids``
+    cell grids: the caller masks each row it scatters to its cell's own.
     """
     price = np.asarray(price)
     B, A = price.shape[0], grid.shape[0]
@@ -722,7 +785,7 @@ def empty_device_stack(grid: np.ndarray, price: np.ndarray,
             raise ValueError(
                 f"coupling.incidence has {coupling.num_cells} rows for "
                 f"{B} cells")
-        link = (jnp.asarray(coupling.link_capacity),
+        link = (jnp.asarray(split_f32(coupling.link_capacity)),
                 jnp.asarray(coupling.incidence),
                 jnp.asarray(coupling.groups()))
     else:
@@ -733,9 +796,9 @@ def empty_device_stack(grid: np.ndarray, price: np.ndarray,
         price=jnp.asarray(price), capacity=jnp.asarray(capacity),
         lat_ok=jnp.zeros((B, tmax, A), bool),
         alive0=jnp.zeros((B, tmax), bool),
-        link_load=jnp.zeros((B, tmax)),
+        link_load=jnp.zeros((B, tmax, 2), jnp.float32),
         link_cap=link[0], incidence=link[1], group=link[2],
-        semantic=bool(semantic), batch_size=B,
+        semantic=bool(semantic), batch_size=B, grids=grids,
     )
 
 
@@ -768,8 +831,8 @@ class ShardedStack:
     capacity: jax.Array              # (B', m) sharded
     lat_ok: jax.Array                # (B', Tmax, A) sharded
     alive0: jax.Array                # (B', Tmax) sharded
-    link_load: jax.Array             # (B', Tmax) sharded
-    link_cap: jax.Array              # (L,) replicated
+    link_load: jax.Array             # (B', Tmax, 2) f32 pairs, sharded
+    link_cap: jax.Array              # (L, 2) f32 pairs, replicated
     incidence: jax.Array             # (B', L) sharded
     group: jax.Array                 # (B',) shard-local group ids
     row_of: np.ndarray               # (B',) stacked row per padded row, -1 pad
@@ -778,6 +841,7 @@ class ShardedStack:
     groups_per_shard: np.ndarray     # (num_shards,) assigned group counts
     padded_of: np.ndarray            # (B,) padded row per stacked row
     coupled: bool = True             # real links (vs the dummy inf link)
+    grids: int = 1                   # distinct cell grids on ``grid``
     scatter_calls: int = 0
     rows_scattered: int = 0
     budget_updates: int = 0
@@ -834,8 +898,7 @@ class ShardedStack:
                 f"of {self.batch_size} rows")
         # stacked row -> padded (shard-blocked) row, then the plain scatter
         p_idx = self.padded_of[b_idx].astype(np.int32)
-        if load_rows is None:
-            load_rows = np.zeros(d)
+        load_rows = split_f32(np.zeros(d) if load_rows is None else load_rows)
         bucket = next_pow2(d)
         pad = bucket - d
         if pad:
@@ -845,13 +908,14 @@ class ShardedStack:
             lat_ok_rows = np.concatenate(
                 [lat_ok_rows, np.zeros((pad,) + lat_ok_rows.shape[1:], bool)])
             alive_rows = np.concatenate([alive_rows, np.zeros(pad, bool)])
-            load_rows = np.concatenate([load_rows, np.zeros(pad)])
+            load_rows = np.concatenate([load_rows,
+                                        np.zeros((pad, 2), np.float32)])
         self.lat_ok, self.alive0, self.link_load = _scatter_rows(
             self.lat_ok, self.alive0, self.link_load,
             jnp.asarray(p_idx), jnp.asarray(t_idx),
             jnp.asarray(np.asarray(lat_ok_rows, bool)),
             jnp.asarray(np.asarray(alive_rows, bool)),
-            jnp.asarray(np.asarray(load_rows, np.float64)))
+            jnp.asarray(load_rows))
         self.scatter_calls += 1
         self.rows_scattered += d
 
@@ -880,12 +944,13 @@ class ShardedStack:
                 "this stack is uncoupled (no link budgets to update); "
                 "introducing links is a topology change — rebuild")
         new = np.asarray(budgets, np.float64)
-        if new.shape != self.link_cap.shape:
+        if new.shape != self.link_cap.shape[:1]:
             raise ValueError(
                 f"budget shape {new.shape} != device link set "
-                f"{self.link_cap.shape}; changing the link set is a "
+                f"{self.link_cap.shape[:1]}; changing the link set is a "
                 "topology change — rebuild the stack")
-        self.link_cap = _scatter_budgets(self.link_cap, jnp.asarray(new))
+        self.link_cap = _scatter_budgets(self.link_cap,
+                                         jnp.asarray(split_f32(new)))
         self.budget_updates += 1
 
 
@@ -1029,12 +1094,13 @@ def device_stack_sharded(stacked: StackedInstances, mesh, *,
         capacity=put(pad(stacked.capacity, 1.0), ("cells", None)),
         lat_ok=put(pad(lat_ok, False), ("cells", None, None)),
         alive0=put(pad(alive0, False), ("cells", None)),
-        link_load=put(pad(load, 0.0), ("cells", None)),
-        link_cap=put(link_cap, (None,)),
+        link_load=put(pad(split_f32(load), 0.0), ("cells", None, None)),
+        link_cap=put(split_f32(link_cap), (None, None)),
         incidence=put(pad(inc, False), ("cells", None)),
         group=put(local_gid, ("cells",)),
         row_of=row_of, batch_size=stacked.batch_size, shard_rows=rows,
         groups_per_shard=gps, padded_of=padded_of, coupled=coupled,
+        grids=stacked.grids,
     )
     cache[key] = shd
     return shd
@@ -1044,7 +1110,8 @@ def empty_sharded_stack(grid: np.ndarray, price: np.ndarray,
                         capacity: np.ndarray, tmax: int, mesh, *,
                         coupling: CouplingSpec | None = None,
                         semantic: bool = True,
-                        axis: str | None = None) -> ShardedStack:
+                        axis: str | None = None,
+                        grids: int = 1) -> ShardedStack:
     """A MESH-RESIDENT stack of cleared rows — :func:`empty_device_stack`
     laid out across the device mesh.
 
@@ -1055,7 +1122,9 @@ def empty_sharded_stack(grid: np.ndarray, price: np.ndarray,
     rows then arrive as perm-addressed delta scatters
     (:meth:`ShardedStack.update_rows`). A coupling-group membership change
     invalidates the plan itself — the session layer rebuilds; budget and
-    semantic drift ride the in-place scatters.
+    semantic drift ride the in-place scatters. As with
+    :func:`empty_device_stack`, ``grid`` may be the union of ``grids`` cell
+    grids, with every scattered row masked to its cell's own.
     """
     if axis is None:
         axis = mesh.axis_names[0]
@@ -1112,12 +1181,14 @@ def empty_sharded_stack(grid: np.ndarray, price: np.ndarray,
         capacity=put(pad(capacity, 1.0), ("cells", None)),
         lat_ok=put(np.zeros((bp, tmax, A), bool), ("cells", None, None)),
         alive0=put(np.zeros((bp, tmax), bool), ("cells", None)),
-        link_load=put(np.zeros((bp, tmax)), ("cells", None)),
-        link_cap=put(link_cap, (None,)),
+        link_load=put(np.zeros((bp, tmax, 2), np.float32),
+                      ("cells", None, None)),
+        link_cap=put(split_f32(link_cap), (None, None)),
         incidence=put(pad(inc, False), ("cells", None)),
         group=put(local_gid, ("cells",)),
         row_of=row_of, batch_size=B, shard_rows=rows,
         groups_per_shard=gps, padded_of=padded_of, coupled=coupled,
+        grids=grids,
     )
 
 

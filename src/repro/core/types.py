@@ -17,6 +17,7 @@ here, so the solvers stay pure-JAX-friendly.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Sequence
 
 import numpy as np
@@ -33,7 +34,9 @@ __all__ = [
     "ProblemInstance",
     "StackedInstances",
     "Solution",
+    "UnionGrid",
     "make_allocation_grid",
+    "union_allocation_grid",
 ]
 
 
@@ -202,6 +205,57 @@ def make_allocation_grid(levels: Sequence[np.ndarray]) -> np.ndarray:
 
 
 @dataclasses.dataclass(frozen=True)
+class UnionGrid:
+    """One allocation grid for cells whose pools enumerate different levels.
+
+    Attributes:
+      grid: (A, m) — :func:`make_allocation_grid` over the per-resource union
+        of the cells' levels.
+      mask: (C, A) bool — the allocations of each cell's own grid, or
+        ``None`` when every cell shares one grid (then ``grid`` is that grid).
+      grids: the number of distinct cell grids.
+
+    Both grids are lexicographic over ascending levels, so a cell's own
+    allocations appear in the union in the order of its own grid: a
+    first-max scan over the masked union picks what a scan over the cell's
+    grid would.
+    """
+
+    grid: np.ndarray
+    mask: np.ndarray | None
+    grids: int = 1
+
+
+def union_allocation_grid(pools: Sequence[ResourcePool]) -> UnionGrid:
+    """The :class:`UnionGrid` of cells with pools ``pools``, one per cell.
+
+    Cells on different grids must have the same resources (``names``), with
+    strictly ascending levels.
+    """
+    keys: dict[tuple, int] = {}
+    cell_key = [keys.setdefault(
+        tuple(tuple(np.asarray(lv, np.float64).tolist()) for lv in p.levels),
+        len(keys)) for p in pools]
+    distinct = list(keys)
+    if len(distinct) == 1:
+        return UnionGrid(make_allocation_grid(pools[0].levels), None)
+    if len({p.names for p in pools}) > 1:
+        raise ValueError(
+            "all cells must have the same resources to share an allocation "
+            f"grid: got {sorted({p.names for p in pools})}")
+    if any(np.any(np.diff(lv) <= 0) for k in distinct for lv in k):
+        raise ValueError(
+            "levels must be strictly ascending to share a union grid")
+    union = [np.unique(np.concatenate([k[r] for k in distinct]))
+             for r in range(len(distinct[0]))]
+    own = np.stack([functools.reduce(
+        np.logical_and.outer, [np.isin(u, lv) for u, lv in zip(union, k)]
+    ).reshape(-1) for k in distinct])
+    return UnionGrid(make_allocation_grid(union), own[cell_key],
+                     len(distinct))
+
+
+@dataclasses.dataclass(frozen=True)
 class ProblemInstance:
     """A fully discretized SF-ESP instance, ready for the solvers.
 
@@ -273,10 +327,12 @@ class StackedInstances:
         Alg. 1 line-7 candidate filter),
       * ``max_latency`` is padded with ``0`` and ``task_mask`` marks real rows.
 
-    All instances must share one enumerated allocation grid — i.e. identical
-    ``pool.levels`` — but capacities and prices MAY differ per instance
-    (multi-cell pools with heterogeneous loads are the intended use); sets
-    with mixed grids dispatch per group via ``greedy.solve_greedy_many``.
+    Instances on one enumerated allocation grid (identical ``pool.levels``)
+    stack onto it; instances on different grids stack onto their
+    :func:`union_allocation_grid`, each row's latency table ``+inf`` off its
+    own grid (``cell_mask``), so no allocation off a cell's grid is ever
+    feasible. Capacities and prices MAY differ per instance (multi-cell
+    pools with heterogeneous loads are the intended use).
     Build via :func:`repro.core.sfesp.stack_instances`; refill in place with
     :func:`repro.core.sfesp.restack` (same grid/batch size, task counts
     within ``Tmax`` — the refilled batch shares these buffers and the old
@@ -297,7 +353,7 @@ class StackedInstances:
     """
 
     instances: tuple[ProblemInstance, ...]
-    grid: np.ndarray                  # (A, m) — shared allocation grid
+    grid: np.ndarray                  # (A, m) — shared (or union) grid
     capacity: np.ndarray              # (B, m) — S_k per instance
     price: np.ndarray                 # (B, m) — p_k per instance
     lat: np.ndarray                   # (B, Tmax, A) — +inf padded
@@ -325,6 +381,9 @@ class StackedInstances:
     # the SemanticModel shared by every instance of the batch (None = paper
     # DEFAULT_MODEL); mixing models in one stack is a build error upstream
     semantics: object | None = None
+    # (B, A) bool — each row's own allocations on a union grid; None when
+    # every instance shares ``grid``
+    cell_mask: np.ndarray | None = None
 
     @property
     def semantic_signature(self) -> tuple[int, int]:
@@ -348,6 +407,13 @@ class StackedInstances:
     @property
     def m(self) -> int:
         return self.grid.shape[1]
+
+    @property
+    def grids(self) -> int:
+        """Distinct cell grids stacked onto ``grid``."""
+        if self.cell_mask is None:
+            return 1
+        return len(np.unique(self.cell_mask, axis=0))
 
     @property
     def group_major(self) -> bool:

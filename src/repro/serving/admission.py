@@ -15,14 +15,15 @@ from typing import Sequence
 import numpy as np
 
 from repro.core import (CouplingSpec, ResourcePool, check_solution,
-                        default_z_grid, make_allocation_grid, next_pow2,
-                        restack, semantics, solve, solve_greedy_batch,
-                        solve_greedy_sharded, stack_instances)
+                        default_z_grid, next_pow2, restack, semantics, solve,
+                        solve_greedy_batch, solve_greedy_sharded,
+                        stack_instances, union_allocation_grid)
 from repro.core import latency as lat_mod
 from repro.core.greedy import (dispatch_device_batch, dispatch_sharded_batch,
                                unpack_device_batch, unpack_sharded_batch)
 from repro.core.sfesp import (DeviceStack, ShardedStack, empty_device_stack,
-                              empty_sharded_stack, task_feasibility_rows)
+                              empty_sharded_stack, stack_grid,
+                              task_feasibility_rows)
 from repro.trace import span
 from .request import SliceRequest
 from .sdla import SDLA
@@ -100,6 +101,9 @@ class _ServeSession:
 
     dev: DeviceStack | ShardedStack
     grid: np.ndarray                 # host copy, for alloc unpack
+    cell_mask: np.ndarray | None     # (B, A) each cell's own allocations on
+    # a union grid (ANDed into every recomputed lat_ok row); None when every
+    # cell is on ``grid``
     z_grid: np.ndarray
     names: list[tuple[str, ...]]     # per-cell resource names
     pools_ref: object                # identity guards: the engine passes the
@@ -178,6 +182,9 @@ class SESM:
         self.fresh_stacks = 0
         self.restacks = 0
         self.delta_rows = 0
+        # of delta_rows, the live rows recomputed under a cell mask that is
+        # not all true (cells off the union grid's every allocation)
+        self.masked_rows = 0
         # admission rounds the device ran, summed over the slot solves (the
         # loop's trip count, read back with the packed decisions)
         self.rounds = 0
@@ -227,9 +234,9 @@ class SESM:
         Empty request sets keep their (vacuous) incidence row.
 
         ``pools`` gives each request set its own resource pool (a multi-cell
-        deployment with heterogeneous capacities); all pools must share one
-        enumerated allocation grid (identical ``levels``). ``None`` keeps this
-        SESM's pool for every set.
+        deployment with heterogeneous capacities, and grids: sets on
+        different ``levels`` stack onto their union grid, each masked to its
+        own). ``None`` keeps this SESM's pool for every set.
 
         Stacking buffers are padded to a power-of-two ``Tmax`` bucket and
         reused (``restack``) across calls with the same number of request
@@ -261,7 +268,7 @@ class SESM:
         tneed = max(inst.num_tasks for inst in insts)
         if (cache is not None and cache.batch_size == len(insts)
                 and cache.max_tasks >= tneed
-                and np.array_equal(cache.grid, insts[0].grid)):
+                and np.array_equal(cache.grid, stack_grid(insts).grid)):
             stacked = restack(cache, insts)
             self.restacks += 1
         else:
@@ -390,8 +397,10 @@ class SESM:
             if not live:
                 return out if wait else PendingSolve.ready(out)
             self.restacks += 1
-        with span("repro.sesm.sync_rows", B=B):
+        masked = self._masked_pending(sess, slot_rows)
+        with span("repro.sesm.sync_rows", B=B, masked=masked):
             self._sync_rows(sess, slot_rows)
+        self.masked_rows += masked
         if isinstance(sess.dev, ShardedStack):
             dispatched = dispatch_sharded_batch(sess.dev, flexible=flexible,
                                                 inner=self.inner)
@@ -428,49 +437,70 @@ class SESM:
                        scale) -> _ServeSession:
         B = len(slot_rows)
         cell_pools = [self.pool] * B if pools is None else list(pools)
-        for pool in cell_pools[1:]:
-            # the same stacking contract solve_batch enforces: one shared
-            # enumerated allocation grid, capacities may differ per cell
-            if len(pool.levels) != len(cell_pools[0].levels) or not all(
-                    np.array_equal(a, b)
-                    for a, b in zip(pool.levels, cell_pools[0].levels)):
-                raise ValueError(
-                    "all slotted cells must share one allocation grid "
-                    "(identical pool.levels); capacities may differ")
-        grid = make_allocation_grid(cell_pools[0].levels)
-        tmax = next_pow2(max([len(rows) for rows in slot_rows] + [1]))
-        price = np.stack([p.price for p in cell_pools])
-        cap = np.stack([p.capacity for p in cell_pools])
-        if self.mesh is not None:
-            # metro mode: the session lives ON the mesh — coupling groups are
-            # shard-planned here, once; every later tick is delta scatters
-            # through that plan plus one shard_map serve
-            dev = empty_sharded_stack(
-                grid, price, cap, tmax, self.mesh, coupling=coupling,
-                semantic=bool(self.algorithm["semantic"]))
-            self.shard_replans += 1
-        else:
-            dev = empty_device_stack(
-                grid, price, cap, tmax, coupling=coupling,
-                semantic=bool(self.algorithm["semantic"]))
-        return _ServeSession(
-            dev=dev, grid=grid, z_grid=default_z_grid(),
-            names=[p.names for p in cell_pools],
-            pools_ref=pools, coupling_ref=coupling,
-            pool_state=self._pool_state(B, pools),
-            link_cap_state=None if coupling is None
-            else coupling.link_capacity.copy(),
-            sem_ref=self.sdla.semantics,
-            sem_version=self.sdla.semantics.version, scale=scale,
-            semantic=bool(self.algorithm["semantic"]),
-            flexible=bool(self.algorithm["flexible"]),
-            z_star=np.ones((B, tmax)), has_z=np.zeros((B, tmax), bool),
-            app_idx=np.zeros((B, tmax), np.int64),
-            bits=np.zeros((B, tmax)), rate=np.zeros((B, tmax)),
-            gpu_t=np.zeros((B, tmax)),
-            pending={(b, t) for b, rows in enumerate(slot_rows)
-                     for t, r in enumerate(rows) if r is not None},
-        )
+        # one grid for every cell: their shared grid, or the union of their
+        # grids with a per-cell mask of each one's own allocations
+        union = union_allocation_grid(cell_pools)
+        grid = union.grid
+        with span("repro.sesm.build_session", B=B, A=grid.shape[0],
+                  grids=union.grids):
+            tmax = next_pow2(max([len(rows) for rows in slot_rows] + [1]))
+            price = np.stack([p.price for p in cell_pools])
+            cap = np.stack([p.capacity for p in cell_pools])
+            semantic = bool(self.algorithm["semantic"])
+            if self.mesh is not None:
+                # metro mode: the session lives ON the mesh — coupling
+                # groups are shard-planned here, once; every later tick is
+                # delta scatters through that plan plus one shard_map serve
+                dev = empty_sharded_stack(
+                    grid, price, cap, tmax, self.mesh, coupling=coupling,
+                    semantic=semantic, grids=union.grids)
+                self.shard_replans += 1
+            else:
+                dev = empty_device_stack(
+                    grid, price, cap, tmax, coupling=coupling,
+                    semantic=semantic, grids=union.grids)
+            return _ServeSession(
+                dev=dev, grid=grid, cell_mask=union.mask,
+                z_grid=default_z_grid(),
+                names=[p.names for p in cell_pools],
+                pools_ref=pools, coupling_ref=coupling,
+                pool_state=self._pool_state(B, pools),
+                link_cap_state=None if coupling is None
+                else coupling.link_capacity.copy(),
+                sem_ref=self.sdla.semantics,
+                sem_version=self.sdla.semantics.version, scale=scale,
+                semantic=semantic,
+                flexible=bool(self.algorithm["flexible"]),
+                z_star=np.ones((B, tmax)), has_z=np.zeros((B, tmax), bool),
+                app_idx=np.zeros((B, tmax), np.int64),
+                bits=np.zeros((B, tmax)), rate=np.zeros((B, tmax)),
+                gpu_t=np.zeros((B, tmax)),
+                pending={(b, t) for b, rows in enumerate(slot_rows)
+                         for t, r in enumerate(rows) if r is not None},
+            )
+
+    @staticmethod
+    def _masked_pending(sess: _ServeSession, slot_rows) -> int:
+        """Live pending rows of cells whose mask is not all true: the rows
+        the next :meth:`_sync_rows` recomputes under a mask."""
+        if sess.cell_mask is None:
+            return 0
+        partial = ~sess.cell_mask.all(axis=1)
+        return sum(1 for b, t in sess.pending
+                   if partial[b] and t < len(slot_rows[b])
+                   and slot_rows[b][t] is not None)
+
+    @staticmethod
+    def _own_rows(sess: _ServeSession, rows, cells: np.ndarray,
+                  pick: np.ndarray | None = None):
+        """(lat_ok, alive) of recomputed ``rows``, those of cells ``cells``
+        (or ``cells[pick]``), restricted to each cell's own grid: ``rows``'
+        own arrays, untouched, on a shared grid."""
+        if sess.cell_mask is None:
+            return rows.lat_ok, rows.alive
+        lat_ok = rows.lat_ok & sess.cell_mask[
+            cells if pick is None else cells[pick]]
+        return lat_ok, (rows.z_idx >= 0) & lat_ok.any(axis=1)
 
     def _sync_rows(self, sess: _ServeSession, slot_rows):
         """Recompute + scatter the pending dirty rows (host AND device)."""
@@ -496,6 +526,8 @@ class SESM:
         bits = np.zeros(d)
         rate = np.zeros(d)
         gpu_t = np.zeros(d)
+        bb = np.fromiter((b for b, _ in items), np.int64, d)
+        tt = np.fromiter((t for _, t in items), np.int64, d)
         if reqs:
             # the ONE per-task min-z pipeline (sfesp.task_feasibility_rows),
             # shared with sdla.build_instance and restricted to the changed
@@ -505,8 +537,7 @@ class SESM:
                 ts, sess.z_grid, sess.grid, self.sdla.lat_params,
                 semantic=sess.semantic, model=self.sdla.semantics)
             li = np.asarray(live_pos, np.int64)
-            lat_ok[li] = rows.lat_ok
-            alive[li] = rows.alive
+            lat_ok[li], alive[li] = self._own_rows(sess, rows, bb, li)
             load[li] = rows.load
             z[li] = rows.z_star
             has_z[li] = rows.z_idx >= 0
@@ -514,8 +545,6 @@ class SESM:
             bits[li] = ts.bits_per_job
             rate[li] = ts.jobs_per_sec
             gpu_t[li] = ts.gpu_time_per_job
-        bb = np.fromiter((b for b, _ in items), np.int64, d)
-        tt = np.fromiter((t for _, t in items), np.int64, d)
         sess.z_star[bb, tt] = z
         sess.has_z[bb, tt] = has_z
         sess.app_idx[bb, tt] = app
@@ -566,8 +595,8 @@ class SESM:
         # (app_idx, bits, rate, gpu_t) are drift-invariant
         sess.z_star[bb, tt] = rows_.z_star
         sess.has_z[bb, tt] = rows_.z_idx >= 0
-        sess.dev.update_semantics(bb, tt, rows_.lat_ok, rows_.alive,
-                                  rows_.load)
+        lat_ok, alive = self._own_rows(sess, rows_, bb)
+        sess.dev.update_semantics(bb, tt, lat_ok, alive, rows_.load)
         self.semantic_updates += 1
 
     def _slot_unpacker(self, sess: _ServeSession, slot_rows, out):

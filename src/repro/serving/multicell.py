@@ -93,9 +93,8 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import gc
 import time
-
-import numpy as np
 
 from repro.core import CouplingSpec, ResourcePool
 from repro.core.events import (Arrival, CellFault, Departure, Event, Handover,
@@ -135,9 +134,11 @@ class MultiCellEngine:
     """N coupled cell runtimes re-sliced jointly through one SESM batch.
 
     Args:
-      pools: one :class:`ResourcePool` per cell. Capacities/prices may
-        differ; ``levels`` must be identical (one shared allocation grid —
-        the batched sweep engine's stacking contract).
+      pools: one :class:`ResourcePool` per cell, all with the same
+        resource ``names``. Capacities/prices may differ, and so may
+        ``levels``: cells on different grids solve on the union grid, each
+        masked to its own allocations (``core.types.union_allocation_grid``),
+        so a link may couple cells of different grids.
       coupling: optional shared-link topology; ``incidence`` needs one row
         per cell. ``None`` re-slices the cells as independent what-ifs
         (still one device program).
@@ -153,6 +154,14 @@ class MultiCellEngine:
         lowest-priority (newest-first) running victim and re-solves the
         freed rows as a delta — the solver itself stays SLA-blind, and only
         the second round's decisions are applied. See :meth:`_preempt_pass`.
+      freeze_heap: after the first commit of every serve session, collect
+        once and move everything the collector then tracks (the JAX
+        runtime, the compiled programs, the session, the live requests) into
+        its permanent generation (``gc.freeze``). The collector's full
+        passes in steady ticks then walk only what the ticks allocated
+        since, a few milliseconds in place of a pause that grows with the
+        whole heap. Reference cycles among the frozen objects are never
+        collected. See :meth:`_freeze_heap`.
     """
 
     def __init__(self, pools: list[ResourcePool], *,
@@ -160,17 +169,17 @@ class MultiCellEngine:
                  max_batch: int = 8, max_retries: int = 2,
                  solver_backend: str = "numpy", mesh=None,
                  tier_policy: TierPolicy | None = None,
-                 preempt: bool = False, heartbeat_timeout: int = 3):
+                 preempt: bool = False, heartbeat_timeout: int = 3,
+                 freeze_heap: bool = True):
         pools = list(pools)
         if not pools:
             raise ValueError("MultiCellEngine needs at least one cell pool")
         for pool in pools[1:]:
-            if len(pool.levels) != len(pools[0].levels) or not all(
-                    np.array_equal(a, b)
-                    for a, b in zip(pool.levels, pools[0].levels)):
+            if pool.names != pools[0].names:
                 raise ValueError(
-                    "all cell pools must share one allocation grid "
-                    "(identical pool.levels); capacities may differ")
+                    "all cell pools must have the same resources "
+                    f"({pools[0].names}, got {pool.names}); capacities and "
+                    "levels may differ")
         if coupling is not None and coupling.num_cells != len(pools):
             raise ValueError(
                 f"coupling.incidence has {coupling.num_cells} rows for "
@@ -213,6 +222,9 @@ class MultiCellEngine:
         self.degraded_ticks = 0                # re-slices run while degraded
         self.sheds = 0                         # TierPolicy pressure sheds
         self.fault_log: list[dict] = []        # fail/recover events, in order
+        self.freeze_heap = freeze_heap
+        self._frozen_session = None            # the session last frozen with
+        self.heap_freezes = 0
 
     @property
     def num_cells(self) -> int:
@@ -613,8 +625,24 @@ class MultiCellEngine:
             if self.preempt:
                 decisions = self._preempt_pass(decisions)
             with span("repro.tick.apply", tick=self.tick):
-                return [cell.apply(ds)
-                        for cell, ds in zip(self.cells, decisions)]
+                out = [cell.apply(ds)
+                       for cell, ds in zip(self.cells, decisions)]
+            if self.freeze_heap and self.sesm._serve_session is not None \
+                    and self.sesm._serve_session is not self._frozen_session:
+                self._freeze_heap()
+            return out
+
+    def _freeze_heap(self):
+        """Collect, then freeze every object the collector tracks, once per
+        serve session: its first commit has compiled its programs, so what
+        is live now lives as long as the session (``freeze_heap``). After
+        the first freeze in a process the collection walks only the objects
+        made since."""
+        with span("repro.tick.freeze_heap", tick=self.tick):
+            gc.collect()
+            gc.freeze()
+        self._frozen_session = self.sesm._serve_session
+        self.heap_freezes += 1
 
     def _preempt_pass(self, decisions: list[list[SliceDecision]]
                       ) -> list[list[SliceDecision]]:

@@ -11,6 +11,7 @@ a collection), never per cell, event or request. Span tree of one tick::
     repro.tick.ingest                       MultiCellEngine.ingest
     repro.tick.dispatch                     MultiCellEngine.reslice_dispatch
       repro.tick.sync_slots                 every cell's sync_slots
+      repro.sesm.build_session              SESM._build_session (when it runs)
       repro.sesm.sync_rows                  SESM._sync_rows
       repro.solve.launch                    inputs snapshot + jitted call
     repro.tick.commit                       MultiCellEngine.reslice_commit
@@ -19,11 +20,17 @@ a collection), never per cell, event or request. Span tree of one tick::
       repro.solve.unpack                    bit unpack, result dict
       repro.sesm.decisions                  SliceDecision objects
       repro.tick.apply                      every cell's apply
+      repro.tick.freeze_heap                gc.collect + gc.freeze, a
+                                            session's first commit only
     repro.gc.gen<N>                         one garbage collection
 
 ``repro.solve.launch`` carries the batch size ``B`` and the name of the
 device program it launches (``program``, as the trace's ``XLA Modules`` line
 names it), so a reduction can pair each launch with the program's execution.
+It and ``repro.sesm.build_session`` also carry the allocation grid's size
+``A`` and the number of distinct cell grids on it, ``grids`` (1 unless cells
+of different levels share a union grid); ``repro.sesm.sync_rows`` carries
+``masked``, the rows it recomputes under a cell mask that is not all true.
 """
 
 from __future__ import annotations

@@ -211,7 +211,7 @@ def test_device_stack_budget_update_guards():
                              coupling=spec)
     dev.update_link_budgets([1.5])
     assert dev.budget_updates == 1
-    assert float(dev.link_cap[0]) == 1.5
+    assert float(dev.link_cap[0].sum()) == 1.5         # (hi, lo) pair
     with pytest.raises(ValueError, match="topology"):
         dev.update_link_budgets([1.0, 2.0])
     plain = empty_device_stack(grid, np.ones((2, 1)), np.ones((2, 1)), 2)

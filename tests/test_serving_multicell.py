@@ -9,6 +9,7 @@ through the bounded retry queue, and handover preserves the achieved-z
 accuracy pin.
 """
 import dataclasses
+import gc
 
 import numpy as np
 import pytest
@@ -59,9 +60,13 @@ def test_multicell_engine_validates_pools_and_coupling():
     with pytest.raises(ValueError, match="rows"):
         MultiCellEngine(pools, coupling=CouplingSpec(
             np.array([1.0]), np.ones((2, 1), bool)))
+    # pools on different grids share one engine (a union grid) ...
     mixed = pools[:2] + [scenarios.multi_cell_pools(4, seed=0, n_grids=2)[1]]
-    with pytest.raises(ValueError, match="grid"):
-        MultiCellEngine(mixed)
+    assert MultiCellEngine(mixed).num_cells == 3
+    # ... pools of different resources do not
+    wider = pools[:2] + [scenarios.multi_cell_pools(1, m=4, seed=0)[0]]
+    with pytest.raises(ValueError, match="same resources"):
+        MultiCellEngine(wider)
     with pytest.raises(ValueError, match="at least one"):
         MultiCellEngine([])
 
@@ -287,6 +292,29 @@ def test_inplace_pool_mutation_invalidates_session():
     eng.reslice()
     assert eng.sesm.fresh_stacks == 2, \
         "capacity edit must invalidate the device session"
+
+
+@pytest.mark.parametrize("freeze", [True, False])
+def test_first_commit_of_each_session_freezes_the_heap(freeze):
+    """With ``freeze_heap`` the first commit of every serve session moves
+    what is live into the collector's permanent generation, once per
+    session; without it the collector keeps tracking everything."""
+    pools = scenarios.multi_cell_pools(2, seed=0)
+    eng = MultiCellEngine(pools, freeze_heap=freeze)
+    eng.submit(_req("coco_bags"), 0)
+    held = [[]]                                   # a container made before
+    eng.reslice()
+    eng.reslice()
+
+    def tracked():
+        return any(o is held for o in gc.get_objects())
+
+    assert eng.heap_freezes == int(freeze)
+    assert tracked() is not freeze
+    pools[0].capacity[:] = pools[0].capacity * 0.5
+    eng.reslice()                                 # a new session
+    assert eng.sesm.fresh_stacks == 2
+    assert eng.heap_freezes == 2 * int(freeze)
 
 
 def test_latency_scale_change_invalidates_session():

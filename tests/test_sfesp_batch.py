@@ -351,3 +351,31 @@ def test_batched_one_jit_call_scales_to_64():
     insts = insts[:64]
     assert len(insts) == 64
     _assert_matches_oracle(insts)
+
+
+def test_feasibility_rows_evaluate_repeated_tasks_once_and_exactly():
+    """A tick's requests repeat a few applications: each distinct task row
+    is evaluated once, and every row equals the direct (T, A) table bit for
+    bit, with repeats and without."""
+    from repro.core import latency as lat_mod
+    from repro.core.sfesp import default_z_grid, task_feasibility_rows
+    from repro.core.types import TaskSet, make_allocation_grid
+
+    pool = scenarios.numerical_pool(2)
+    base = scenarios.numerical_tasks(12, "med", "high", seed=3)
+    pick = np.random.default_rng(0).integers(0, 12, 200)
+    repeated = TaskSet(**{f.name: getattr(base, f.name)[pick]
+                          for f in dataclasses.fields(base)})
+    grid = make_allocation_grid(pool.levels)
+    z_grid = default_z_grid()
+    for tasks in (base, repeated):
+        rows = task_feasibility_rows(tasks, z_grid, grid)
+        direct = lat_mod.latency_table(lat_mod.LatencyParams(), tasks,
+                                       rows.z_star, grid)
+        np.testing.assert_array_equal(rows.lat, direct)
+        np.testing.assert_array_equal(
+            rows.lat_ok, direct <= tasks.max_latency[:, None])
+    with mock.patch.object(lat_mod, "latency", wraps=lat_mod.latency) as spy:
+        task_feasibility_rows(repeated, z_grid, grid)
+    assert spy.call_count == 1
+    assert spy.call_args.args[1].shape[0] <= 12       # distinct rows only

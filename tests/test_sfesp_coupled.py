@@ -181,14 +181,26 @@ def test_merge_coupling_validates_link_set():
 
 
 def test_many_rejects_link_across_grid_groups():
+    """A link whose users sit on two grids is solved once, on their union
+    grid: the decisions are the coupled oracle's, each allocation on its
+    own cell's grid, and the budget binds."""
     pools = scenarios.multi_cell_pools(2, seed=3, n_grids=2)  # distinct grids
     spec = CouplingSpec(np.array([5.0]), np.ones((1, 1), bool))
     insts = [build_instance(p, scenarios.numerical_tasks(6, "med", "high",
                                                          seed=s),
                             coupling=spec)
              for s, p in enumerate(pools)]
-    with pytest.raises(ValueError, match="span grid groups"):
-        solve_greedy_many(insts)
+    assert insts[0].num_allocs != insts[1].num_allocs
+    sols = solve_greedy_many(insts)
+    for inst, sol, ref in zip(insts, sols, solve_coupled_ref(insts)):
+        assert (sol.admitted == ref.admitted).all()
+        assert np.allclose(sol.alloc, ref.alloc)
+        own = {tuple(row) for row in inst.grid.tolist()}
+        assert all(tuple(a) in own for a in sol.alloc[sol.admitted].tolist())
+    free = solve_greedy_many([dataclasses.replace(i, coupling=None)
+                              for i in insts])
+    assert sum(s.num_allocated for s in sols) \
+        < sum(s.num_allocated for s in free)
 
 
 def test_many_dispatches_coupled_groups():
@@ -233,8 +245,20 @@ def test_multi_cell_trace_shared_backhaul_one_group_per_step():
 
 
 def test_shared_backhaul_rejects_mixed_grids():
-    with pytest.raises(ValueError, match="n_grids"):
-        scenarios.multi_cell_trace(4, 2, n_grids=2, shared_backhaul=5.0)
+    """A shared-backhaul trace over two grids builds, and stacks whole onto
+    the union grid: its coupled solve equals the oracle."""
+    insts, meta = scenarios.multi_cell_trace(4, 2, n_grids=2, seed=4,
+                                             shared_backhaul=5.0)
+    assert len({i.num_allocs for i in insts}) == 2
+    st = stack_instances(insts)
+    assert st.grids == 2 and st.cell_mask.shape == (8, st.num_allocs)
+    for b, inst in enumerate(insts):
+        assert st.cell_mask[b].sum() == inst.num_allocs
+    for sol, ref in zip(solve_greedy_batch(st), solve_coupled_ref(insts)):
+        assert (sol.admitted == ref.admitted).all()
+        assert np.allclose(sol.alloc, ref.alloc)
+    for sol, ref in zip(solve_greedy_many(insts), solve_coupled_ref(insts)):
+        assert (sol.admitted == ref.admitted).all()
 
 
 def test_shared_backhaul_binds_admission():
@@ -284,3 +308,28 @@ def test_handover_warm_start_pins_compression():
         assert (zi >= 0).all()
         assert (z_grid[zi] <= z_grid + 1e-12).all()
         assert (semantics.accuracy(idx, z_grid[zi]) >= acc_at - 1e-9).all()
+
+
+def test_link_budget_binds_as_in_float64():
+    """Sixty one-task cells on one link, admitted in cell order; the budget
+    leaves the last task 3e-6 short. A float32 running sum of the first 59
+    loads (about 61.8) errs by more than that and would admit it; the
+    device's float32-pair accounting gives float64's verdict."""
+    from repro.core.greedy import _greedy_jax_batch_coupled
+    from repro.core.sfesp import split_f32
+
+    loads = np.random.default_rng(9).uniform(0.5, 1.5, 60)
+    budget = loads[:-1].sum() + loads[-1] - 3e-6
+    used = np.float32(0)
+    for load in loads[:-1]:
+        used = np.float32(used + np.float32(load))
+    assert np.float32(loads[-1]) <= np.float32(budget) - used  # the premise
+    B = len(loads)
+    grid = np.array([[1.0, 1.0]])
+    admitted, _, _ = _greedy_jax_batch_coupled(
+        np.ones((B, 1, 1), bool), grid, np.ones((B, 2)),
+        np.full((B, 2), 4.0), np.ones((B, 1), bool), np.zeros(1),
+        split_f32(loads[:, None]), split_f32([budget]),
+        np.ones((B, 1), bool), np.zeros(B, np.int32))
+    admitted = np.asarray(admitted)[:, 0]
+    assert admitted[:-1].all() and not admitted[-1]

@@ -86,7 +86,7 @@ def _coupled_args(spec, b, tmax, a, m, links):
     return (spec((b, tmax, a), jnp.bool_), spec((a, m), jnp.float32),
             spec((b, m), jnp.float32), spec((b, m), jnp.float32),
             spec((b, tmax), jnp.bool_), spec((a,), jnp.float32),
-            spec((b, tmax), jnp.float32), spec((links,), jnp.float32),
+            spec((b, tmax, 2), jnp.float32), spec((links, 2), jnp.float32),
             spec((b, links), jnp.bool_), spec((b,), jnp.int32))
 
 

@@ -116,6 +116,8 @@ def test_tick_spans_nest_as_documented(tmp_path):
         "repro.tick.ingest": None,
         "repro.tick.dispatch": None,
         "repro.tick.sync_slots": "repro.tick.dispatch",
+        # the third request per cell outgrows the Tmax bucket of two
+        "repro.sesm.build_session": "repro.tick.dispatch",
         "repro.sesm.sync_rows": "repro.tick.dispatch",
         "repro.solve.launch": "repro.tick.dispatch",
         "repro.tick.commit": None,
@@ -124,6 +126,8 @@ def test_tick_spans_nest_as_documented(tmp_path):
         "repro.solve.unpack": "repro.tick.commit",
         "repro.sesm.decisions": "repro.tick.commit",
         "repro.tick.apply": "repro.tick.commit",
+        # the new session's first commit freezes the heap
+        "repro.tick.freeze_heap": "repro.tick.commit",
     }
     assert len(spans) == len(parents)            # each phase once per tick
     top = [s[0] for s in spans if parents[s[0]] is None]
@@ -134,8 +138,14 @@ def test_tick_spans_nest_as_documented(tmp_path):
     launch = next(s for s in spans if s[0] == "repro.solve.launch")
     assert launch[4]["program"] == "jit__serve_batch_coupled"
     assert launch[4]["B"] == 3
+    build = next(s for s in spans if s[0] == "repro.sesm.build_session")
+    for s in (launch, build):
+        assert (s[4]["A"], s[4]["grids"]) == (300, 1)
+    sync = next(s for s in spans if s[0] == "repro.sesm.sync_rows")
+    assert sync[4]["masked"] == 0
     assert sum(len(ds) for ds in decisions) == 9
     assert eng.metrics()["totals"]["rounds"] > rounds0
+    assert eng.heap_freezes == 2                 # one per session
 
 
 def test_gc_spans_record_collections_and_install_once(tmp_path):
