@@ -11,7 +11,7 @@ from repro.core.events import (Arrival, CellFault, Departure, Event,
 
 from .request import SliceRequest
 from .sdla import SDLA
-from .admission import SESM, PendingSolve, SliceDecision
+from .admission import SESM, CellDecisions, PendingSolve, SliceDecision
 from .engine import (CellRuntime, EdgeServingEngine, TaskRuntime,
                      pinned_accuracy_at)
 from .multicell import MultiCellEngine, TierPolicy
@@ -20,8 +20,9 @@ from .driver import drive_closed_loop, sla_scorecard
 __all__ = [
     "Arrival", "CellFault", "Departure", "Event", "Handover", "LinkScale",
     "SemanticShift", "Tick",
-    "SliceRequest", "SDLA", "SESM", "PendingSolve", "SliceDecision",
-    "CellRuntime", "EdgeServingEngine", "TaskRuntime", "pinned_accuracy_at",
+    "SliceRequest", "SDLA", "SESM", "CellDecisions", "PendingSolve",
+    "SliceDecision", "CellRuntime", "EdgeServingEngine", "TaskRuntime",
+    "pinned_accuracy_at",
     "MultiCellEngine", "TierPolicy",
     "drive_closed_loop", "sla_scorecard",
 ]

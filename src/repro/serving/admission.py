@@ -9,6 +9,7 @@ equally considered — already-running tasks may be evicted, Section III-C).
 
 from __future__ import annotations
 
+import collections.abc
 import dataclasses
 from typing import Sequence
 
@@ -28,7 +29,8 @@ from repro.trace import span
 from .request import SliceRequest
 from .sdla import SDLA
 
-__all__ = ["PendingSolve", "SliceDecision", "SESM"]
+__all__ = ["CellDecisions", "DecisionColumns", "PendingSolve",
+           "SliceDecision", "SESM"]
 
 
 class PendingSolve:
@@ -75,6 +77,149 @@ class SliceDecision:
     # evicted a previously-RUNNING task (vs turning away a pending request)
     cell: int | None = None
     evicted: bool = False
+
+
+class DecisionColumns:
+    """One solve's decisions as columns, each cell's rows contiguous.
+
+    Columns, one row per decision: ``requests`` (the solved rows), ``rid``
+    (their request ids), ``slot`` (the solver-row slot each was decided in;
+    ``None`` for decisions that came as objects), ``admitted``, ``z``,
+    ``alloc`` ((n, m)), ``latency`` and ``accuracy`` (the expected ones) and
+    ``evicted``, the one column ``CellRuntime.apply`` writes. ``built``
+    holds the rows' :class:`SliceDecision` objects once made; ``sesm``
+    counts those it makes (``SESM.decisions_built``).
+    """
+
+    __slots__ = ("requests", "rid", "slot", "admitted", "z", "alloc",
+                 "latency", "accuracy", "evicted", "built", "sesm")
+
+    def __init__(self, requests, rid, slot, admitted, z, alloc, latency,
+                 accuracy, sesm: "SESM | None" = None):
+        self.requests = requests
+        self.rid = rid
+        self.slot = slot
+        self.admitted = admitted
+        self.z = z
+        self.alloc = alloc
+        self.latency = latency
+        self.accuracy = accuracy
+        self.evicted = np.zeros(len(requests), bool)
+        self.built: list[SliceDecision | None] | None = None
+        self.sesm = sesm
+
+    def decision(self, i: int, names: tuple[str, ...],
+                 cell: int | None) -> SliceDecision:
+        """Row ``i``'s object, built on first use."""
+        if self.built is None:
+            self.built = [None] * len(self.requests)
+        d = self.built[i]
+        if d is None:
+            d = self.built[i] = SliceDecision(
+                request=self.requests[i],
+                admitted=bool(self.admitted[i]),
+                z=float(self.z[i]),
+                alloc={n: float(self.alloc[i, k])
+                       for k, n in enumerate(names)},
+                expected_latency_s=float(self.latency[i]),
+                expected_accuracy=float(self.accuracy[i]),
+                cell=cell,
+                evicted=bool(self.evicted[i]),
+            )
+            if self.sesm is not None:
+                self.sesm.decisions_built += 1
+        return d
+
+    def flag_evicted(self, rows) -> None:
+        """Mark ``rows`` as evictions, in the column and on built objects."""
+        self.evicted[rows] = True
+        if self.built is not None:
+            for i in np.atleast_1d(rows).tolist():
+                if self.built[i] is not None:
+                    self.built[i].evicted = True
+
+
+class CellDecisions(collections.abc.Sequence):
+    """One cell's decisions of one re-slice: a view over rows
+    ``[lo, hi)`` of the solve's :class:`DecisionColumns`.
+
+    What :meth:`SESM.solve_slots` returns per cell. It reads exactly as the
+    list of :class:`SliceDecision` it stands for (same values, same order;
+    ``==`` against such a list holds), while ``CellRuntime.apply`` works on
+    the columns alone. Row ``i``'s object is built the first time it is
+    read and then kept, so a tick whose decisions nobody reads builds none
+    (``SESM.decisions_built``). The ``evicted`` flags ``apply`` writes show
+    in the column and on built objects alike.
+    """
+
+    __slots__ = ("columns", "lo", "hi", "cell", "names")
+
+    def __init__(self, columns: DecisionColumns, lo: int, hi: int,
+                 cell: int | None, names: tuple[str, ...]):
+        self.columns = columns
+        self.lo = lo
+        self.hi = hi
+        self.cell = cell
+        self.names = names
+
+    @classmethod
+    def of(cls, decisions: Sequence[SliceDecision]) -> "CellDecisions":
+        """The columns of decisions that exist as objects; the objects are
+        the built rows, so flags written through the columns reach them."""
+        ds = list(decisions)
+        n = len(ds)
+        names = tuple(ds[0].alloc) if ds else ()
+        cols = DecisionColumns(
+            [d.request for d in ds],
+            np.fromiter((d.request.request_id for d in ds), np.int64, n),
+            None,
+            np.fromiter((d.admitted for d in ds), bool, n),
+            np.fromiter((d.z for d in ds), float, n),
+            np.array([[d.alloc[k] for k in names] for d in ds],
+                     float).reshape(n, len(names)),
+            np.fromiter((d.expected_latency_s for d in ds), float, n),
+            np.fromiter((d.expected_accuracy for d in ds), float, n))
+        cols.evicted[:] = [d.evicted for d in ds]
+        cols.built = ds
+        return cls(cols, 0, n, ds[0].cell if ds else None, names)
+
+    def __len__(self) -> int:
+        return self.hi - self.lo
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return [self[j] for j in range(*i.indices(len(self)))]
+        n = self.hi - self.lo
+        if not -n <= i < n:
+            raise IndexError("decision index out of range")
+        return self.columns.decision(self.lo + i % n, self.names, self.cell)
+
+    def __iter__(self):
+        decision = self.columns.decision
+        for i in range(self.lo, self.hi):
+            yield decision(i, self.names, self.cell)
+
+    def __eq__(self, other):
+        if isinstance(other, (CellDecisions, list, tuple)):
+            return list(self) == list(other)
+        return NotImplemented
+
+    __hash__ = None
+
+    def __repr__(self) -> str:
+        return (f"CellDecisions(cell={self.cell}, rows={len(self)}, "
+                f"admitted={int(self.admitted.sum())})")
+
+    # the cell's rows of the columns apply and its tests read
+    rid = property(lambda self: self.columns.rid[self.lo:self.hi])
+    admitted = property(lambda self: self.columns.admitted[self.lo:self.hi])
+    evicted = property(lambda self: self.columns.evicted[self.lo:self.hi])
+    alloc = property(lambda self: self.columns.alloc[self.lo:self.hi])
+
+    @property
+    def slot(self) -> np.ndarray | None:
+        s = self.columns.slot
+        return None if s is None else s[self.lo:self.hi]
 
 
 @dataclasses.dataclass
@@ -126,6 +271,7 @@ class _ServeSession:
     semantic: bool
     flexible: bool
     # host mirrors, (B, Tmax) each
+    rid: np.ndarray                  # request id, -1 on a cleared row
     z_star: np.ndarray
     has_z: np.ndarray
     app_idx: np.ndarray
@@ -203,6 +349,9 @@ class SESM:
         # build — a coupling-group membership change is the only way to force
         # a replan once the session is warm; budget/semantic drift must not)
         self.shard_replans = 0
+        # SliceDecision objects built from the slot solves' columns
+        # (CellDecisions): 0 over ticks whose decisions nobody reads
+        self.decisions_built = 0
 
     def slice(self, requests: list[SliceRequest]) -> list[SliceDecision]:
         if not requests:
@@ -307,7 +456,9 @@ class SESM:
         returns the packed decisions (admitted bitmask, s*, residual
         capacities, link loads) in a single small buffer. Decisions per live
         slot are identical to :meth:`solve_batch` on the compacted request
-        sets (cleared rows are never feasible and cannot shift tie-breaks).
+        sets (cleared rows are never feasible and cannot shift tie-breaks);
+        each cell's come as a :class:`CellDecisions` over the tick's
+        columns, which builds a :class:`SliceDecision` only when read.
 
         The session rebuilds (a fresh stack) when the Tmax bucket overflows,
         the batch size / algorithm / coupling / pools change, or the SDLA
@@ -347,7 +498,7 @@ class SESM:
         if pools is not None and len(pools) != B:
             raise ValueError(
                 f"got {len(pools)} pools for {B} slot sets")
-        out: list[list[SliceDecision]] = [[] for _ in range(B)]
+        out: list[Sequence[SliceDecision]] = [[] for _ in range(B)]
         live = any(r is not None for rows in slot_rows for r in rows)
         tneed = max([len(rows) for rows in slot_rows] + [1])
         scale = self.sdla.latency_scale
@@ -471,6 +622,7 @@ class SESM:
                 sem_version=self.sdla.semantics.version, scale=scale,
                 semantic=semantic,
                 flexible=bool(self.algorithm["flexible"]),
+                rid=np.full((B, tmax), -1, np.int64),
                 z_star=np.ones((B, tmax)), has_z=np.zeros((B, tmax), bool),
                 app_idx=np.zeros((B, tmax), np.int64),
                 bits=np.zeros((B, tmax)), rate=np.zeros((B, tmax)),
@@ -519,6 +671,7 @@ class SESM:
         # cleared-row defaults: never feasible, never alive, padding scalars
         lat_ok = np.zeros((d, A), bool)
         alive = np.zeros(d, bool)
+        rid = np.full(d, -1, np.int64)
         load = np.zeros(d)
         z = np.ones(d)
         has_z = np.zeros(d, bool)
@@ -538,6 +691,7 @@ class SESM:
                 semantic=sess.semantic, model=self.sdla.semantics)
             li = np.asarray(live_pos, np.int64)
             lat_ok[li], alive[li] = self._own_rows(sess, rows, bb, li)
+            rid[li] = [r.request_id for r in reqs]
             load[li] = rows.load
             z[li] = rows.z_star
             has_z[li] = rows.z_idx >= 0
@@ -545,6 +699,7 @@ class SESM:
             bits[li] = ts.bits_per_job
             rate[li] = ts.jobs_per_sec
             gpu_t[li] = ts.gpu_time_per_job
+        sess.rid[bb, tt] = rid
         sess.z_star[bb, tt] = z
         sess.has_z[bb, tt] = has_z
         sess.app_idx[bb, tt] = app
@@ -603,13 +758,17 @@ class SESM:
         """Build the decision unpacker for one dispatched slot solve.
 
         Snapshots everything the unpack needs from the session's host
-        mirrors AT DISPATCH TIME — live positions, per-row z*/app/stream
-        scalars, request objects, resource names, latency params — so the
-        returned closure depends only on the device result. That snapshot is
-        the host half of the double buffer: a ``wait=False`` caller keeps
-        ingesting events (which may dirty rows and later overwrite the
-        mirrors) while the solve is in flight, and the unpack still reports
-        against the state that was actually solved.
+        mirrors AT DISPATCH TIME — live positions, request ids, per-row
+        z*/app/stream scalars, request objects, resource names, latency
+        params — so the returned closure depends only on the device result.
+        That snapshot is the host half of the double buffer: a
+        ``wait=False`` caller keeps ingesting events (which may dirty rows
+        and later overwrite the mirrors) while the solve is in flight, and
+        the unpack still reports against the state that was actually solved.
+
+        The unpack returns one :class:`CellDecisions` per cell with live
+        rows (an empty list otherwise): each cell's rows are a contiguous
+        range of the b-major positions, cut out of the tick's columns.
         """
         pos = [(b, t) for b, rows in enumerate(slot_rows)
                for t, r in enumerate(rows) if r is not None]
@@ -618,6 +777,7 @@ class SESM:
         bb = np.fromiter((b for b, _ in pos), np.int64, len(pos))
         tt = np.fromiter((t for _, t in pos), np.int64, len(pos))
         # fancy indexing copies: these are value snapshots, not views
+        rid = sess.rid[bb, tt]
         has_z = sess.has_z[bb, tt]
         z_star = sess.z_star[bb, tt]
         app_idx = sess.app_idx[bb, tt]
@@ -632,6 +792,8 @@ class SESM:
         # is in flight must not change what the unpack reports (the accuracy
         # half of the double buffer)
         model = self.sdla.semantics.snapshot()
+        # each cell's rows: pos[cut[b]:cut[b + 1]]
+        cut = np.searchsorted(bb, np.arange(len(slot_rows) + 1)).tolist()
 
         def unpack(res):
             adm = res["admitted"][bb, tt]
@@ -642,17 +804,11 @@ class SESM:
             # _decisions/check_solution
             lat = lat_mod.latency(lat_params, bits, rate, gpu_t, z, alloc)
             acc = model.accuracy(app_idx, z)
-            for i, (b, t) in enumerate(pos):
-                out[b].append(SliceDecision(
-                    request=reqs[i],
-                    admitted=bool(adm[i]),
-                    z=float(z[i]),
-                    alloc={n: float(alloc[i, k])
-                           for k, n in enumerate(names[b])},
-                    expected_latency_s=float(lat[i]),
-                    expected_accuracy=float(acc[i]),
-                    cell=b,
-                ))
+            cols = DecisionColumns(reqs, rid, tt, adm, z, alloc, lat, acc,
+                                   self)
+            for b, (lo, hi) in enumerate(zip(cut, cut[1:])):
+                if lo < hi:
+                    out[b] = CellDecisions(cols, lo, hi, b, names[b])
             return out
 
         return unpack

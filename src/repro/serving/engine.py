@@ -30,25 +30,29 @@ slot-indexed numpy tables that mirror the solver rows one-to-one: ``_rid``
 ``_retries_left``, ``_pin`` (handover warm-start accuracy bound, 0.0 =
 unpinned), ``_gen`` (per-arrival generation), ``_deadline`` / ``_bits``
 (SLA deadline and resolved stream size), ``_dirty`` (accumulated
-changed-row bits) and the ``_sig_gen`` / ``_sig_pin`` signatures of the
-last consumed sync. A request is seated in the lowest free slot at the
-first :meth:`CellRuntime.sync_slots` after it arrives (a min-heap of freed
-slots keeps assignment identical to the old candidate-order walk), keeps
-that slot for as long as it stays a candidate, and frees it on departure/
-drop/handover — so slot sync is a vectorized signature compare over the
-tables plus a ``flatnonzero`` of the dirty bits instead of a Python loop
-over request objects, and event ingestion between ticks costs O(1) numpy
-scalar writes per event. Three slot-indexed object tables ride along for
-the parts that are inherently per-object: ``_req`` (the original request),
-``_row`` (the solver-row view with the pin applied — what ``sync_slots``
-returns without re-deriving), and ``_rt`` (the live or parked
-:class:`TaskRuntime`).
+changed-row bits), the ``_sig_gen`` / ``_sig_pin`` signatures of the
+last consumed sync and ``_drow`` (a running task's row in the cell's newest
+decisions). Each is the cell's row of a :class:`SlotTables` that all the
+cells of an engine share, so :func:`apply_decisions` applies every cell's
+decisions at once, as columns. A request is seated in the lowest free slot
+at the first :meth:`CellRuntime.sync_slots` after it arrives (a min-heap of
+freed slots keeps assignment identical to the old candidate-order walk),
+keeps that slot for as long as it stays a candidate, and frees it on
+departure/drop/handover — so slot sync is a vectorized signature compare
+over the tables plus a ``flatnonzero`` of the dirty bits instead of a
+Python loop over request objects, and event ingestion between ticks costs
+O(1) numpy scalar writes per event. Three slot-indexed object tables ride
+along for the parts that are inherently per-object: ``_req`` (the
+original request), ``_row`` (the solver-row view with the pin applied —
+what ``sync_slots`` returns without re-deriving), and ``_rt`` (the live or
+parked :class:`TaskRuntime`).
 
 The FIFO queue is a list of ``(rid, gen)`` entries with LAZY deletion: a
 departure of a queued request only detaches its id from the tables (O(1));
 the stale queue entry is skipped by generation mismatch wherever the queue
-is read and physically purged by the per-tick rebuild in :meth:`apply` —
-so a churn-heavy event window never pays O(queue) per departure.
+is read and physically purged by the per-tick rebuild in
+:func:`apply_decisions` — so a churn-heavy event window never pays O(queue)
+per departure.
 """
 
 from __future__ import annotations
@@ -56,7 +60,9 @@ from __future__ import annotations
 import collections
 import dataclasses
 import heapq
+import itertools
 import time
+from typing import Sequence
 
 import jax
 import numpy as np
@@ -65,7 +71,7 @@ from repro.core import ResourcePool, semantics
 from repro.core.latency import latency as latency_model
 from repro.data.pipeline import FrameStream
 from repro.kernels.resize import ops as resize_ops
-from .admission import SliceDecision
+from .admission import CellDecisions, SliceDecision
 from .request import SliceRequest
 from .sdla import SDLA
 
@@ -74,14 +80,106 @@ __all__ = ["CellRuntime", "EdgeServingEngine", "TaskRuntime",
 
 # slot states
 _FREE, _QUEUED, _RUNNING = 0, 1, 2
+# what a freed slot's table fields are reset to: a cleared row, dirty, and
+# signatures reset so a future re-seating re-dirties it even across a
+# consuming sync
+_FREED = (("_rid", -1), ("_state", _FREE), ("_pin", 0.0), ("_dirty", True),
+          ("_sig_gen", -1), ("_sig_pin", -1.0), ("_drow", -1))
 
 
-@dataclasses.dataclass
 class TaskRuntime:
-    decision: SliceDecision
-    jobs_done: int = 0
-    jobs_dropped: int = 0
-    latencies: list = dataclasses.field(default_factory=list)
+    """An admitted task's runtime: its newest admitted decision and its
+    job/latency history (which a rejected task keeps, parked, until it is
+    admitted again or leaves).
+
+    ``decision`` is built from the columns of the batch that admitted the
+    task (:class:`~repro.serving.admission.CellDecisions`) the first time
+    it is read. While the task runs, the runtime reads it through its cell:
+    the cell's newest batch, at the row its slot records (``_drow``), so an
+    apply that admits it again moves no Python object. Once the task stops
+    running there (evicted, preempted, handed over, withdrawn, or carried
+    past an apply that did not decide it) the runtime keeps that batch and
+    row itself.
+    """
+
+    __slots__ = ("_decision", "_batch", "_row", "_cell", "_at", "jobs_done",
+                 "jobs_dropped", "latencies")
+
+    def __init__(self, decision: SliceDecision | None = None,
+                 jobs_done: int = 0, jobs_dropped: int = 0,
+                 latencies: list | None = None):
+        self._decision = decision
+        self._batch: CellDecisions | None = None     # an older batch, or
+        self._row = 0
+        self._cell: CellRuntime | None = None        # the cell it runs in,
+        self._at = 0                                 # at this slot
+        self.jobs_done = jobs_done
+        self.jobs_dropped = jobs_dropped
+        self.latencies = [] if latencies is None else latencies
+
+    @property
+    def decision(self) -> SliceDecision:
+        cell = self._cell
+        if cell is not None:
+            return cell._batch[int(cell._drow[self._at])]
+        if self._batch is not None:
+            return self._batch[self._row]
+        return self._decision
+
+    @decision.setter
+    def decision(self, d: SliceDecision):
+        if self._cell is not None:
+            self._cell._drow[self._at] = -1
+        self._decision = d
+        self._batch = self._cell = None
+
+
+class SlotTables:
+    """The numpy halves of cells' slot tables, one row per cell.
+
+    Each :class:`CellRuntime`'s ``_rid``, ``_state``, ... are views of its
+    row, so :func:`apply_decisions` indexes every cell's slots with one
+    ``(row, slot)`` gather or scatter a field. The rows share one capacity:
+    growing a cell grows them all.
+    """
+
+    _FIELDS = (("_rid", np.int64, -1), ("_state", np.int8, _FREE),
+               ("_tier", np.int32, 0), ("_retries_left", np.int32, 0),
+               ("_pin", np.float64, 0.0),       # 0.0 = unpinned
+               ("_gen", np.int64, 0),
+               ("_deadline", np.float64, 0.0),  # request.max_latency_s
+               ("_bits", np.float64, 0.0),      # resolved stream Mbit/job
+               ("_dirty", bool, False), ("_sig_gen", np.int64, -1),
+               ("_sig_pin", np.float64, -1.0),
+               # the running task's row in its cell's newest batch; -1 where
+               # no task runs or its runtime keeps its own (TaskRuntime)
+               ("_drow", np.int64, -1))
+
+    def __init__(self, rows: int, cap: int = 8):
+        self.cap = cap
+        self.cells: list[CellRuntime] = []
+        for name, dtype, fill in self._FIELDS:
+            setattr(self, name, np.full((rows, cap), fill, dtype))
+
+    def add(self, cell: "CellRuntime") -> int:
+        """Give ``cell`` the next row; returns its index."""
+        self.cells.append(cell)
+        return len(self.cells) - 1
+
+    def grow(self, need: int):
+        new = max(self.cap * 2, need)
+        for name, dtype, fill in self._FIELDS:
+            old = getattr(self, name)
+            arr = np.full((old.shape[0], new), fill, dtype)
+            arr[:, :self.cap] = old
+            setattr(self, name, arr)
+        pad = [None] * (new - self.cap)
+        self.cap = new
+        for cell in self.cells:
+            cell._req += pad
+            cell._row += pad
+            cell._rt += pad
+            cell._views()
 
 
 class CellRuntime:
@@ -100,11 +198,14 @@ class CellRuntime:
     ``registry`` is an optional shared ``{request_id: cell}`` index (the
     engine-level O(1) ``locate``): every path a request enters or leaves the
     cell through keeps it consistent — submit, hand-in, departure, handover,
-    drain, shed, retry-exhaustion drop.
+    drain, shed, retry-exhaustion drop. ``tables`` holds the cell's numpy
+    slot tables as one row of a :class:`SlotTables` shared by the engine's
+    cells.
     """
 
-    def __init__(self, pool: ResourcePool, sdla: SDLA, *, max_batch: int = 8,
-                 max_retries: int = 2, cell: int | None = None,
+    def __init__(self, pool: ResourcePool, sdla: SDLA, *, tables: SlotTables,
+                 max_batch: int = 8, max_retries: int = 2,
+                 cell: int | None = None,
                  registry: dict[int, int] | None = None):
         self.pool = pool
         self.sdla = sdla
@@ -127,6 +228,9 @@ class CellRuntime:
         # (MultiCellEngine's post-solve preemption pass) — a subset of
         # `evictions`, attributed to the EVICTED task's tier
         self.preemptions = 0
+        # rows whose state an apply (or a preemption) changed: admitted from
+        # the queue, evicted, or dropped
+        self.moved = 0
         self.offered_by_tier: collections.Counter = collections.Counter()
         self.admitted_by_tier: collections.Counter = collections.Counter()
         self.evictions_by_tier: collections.Counter = collections.Counter()
@@ -134,21 +238,15 @@ class CellRuntime:
         self.sheds_by_tier: collections.Counter = collections.Counter()
         self.preemptions_by_tier: collections.Counter = collections.Counter()
         # ------------------------------------------------ SoA slot tables
-        # numpy halves (slot index == solver row; see the module docstring)
-        cap = 8
-        self._cap = cap
+        # numpy halves (slot index == solver row; see the module docstring):
+        # this cell's row of ``tables``, shared with the engine's cells
+        self._tables = tables
+        self._trow = self._tables.add(self)
+        self._views()
         self._hi = 0                              # slot high-watermark
-        self._rid = np.full(cap, -1, np.int64)
-        self._state = np.zeros(cap, np.int8)
-        self._tier = np.zeros(cap, np.int32)
-        self._retries_left = np.zeros(cap, np.int32)
-        self._pin = np.zeros(cap)                 # 0.0 = unpinned
-        self._gen = np.zeros(cap, np.int64)
-        self._deadline = np.zeros(cap)            # request.max_latency_s
-        self._bits = np.zeros(cap)                # resolved stream Mbit/job
-        self._dirty = np.zeros(cap, bool)
-        self._sig_gen = np.full(cap, -1, np.int64)
-        self._sig_pin = np.full(cap, -1.0)
+        # the decisions of the newest apply; running tasks' rows in _drow
+        self._batch: CellDecisions | None = None
+        cap = self._tables.cap
         # object halves (slot-indexed)
         self._req: list[SliceRequest | None] = [None] * cap
         self._row: list[SliceRequest | None] = [None] * cap
@@ -166,38 +264,40 @@ class CellRuntime:
         self.step = 0
 
     # ------------------------------------------------------- SoA plumbing
-    def _grow(self, need: int):
-        new = max(self._cap * 2, need)
-        for name in ("_rid", "_state", "_tier", "_retries_left", "_pin",
-                     "_gen", "_deadline", "_bits", "_dirty", "_sig_gen",
-                     "_sig_pin"):
-            old = getattr(self, name)
-            arr = np.zeros(new, old.dtype)
-            arr[:self._cap] = old
-            if name == "_rid" or name == "_sig_gen":
-                arr[self._cap:] = -1
-            elif name == "_sig_pin":
-                arr[self._cap:] = -1.0
-            setattr(self, name, arr)
-        pad = [None] * (new - self._cap)
-        self._req += pad
-        self._row += pad
-        self._rt += pad
-        self._cap = new
+    def _views(self):
+        """Point the table fields at this cell's row of ``_tables``."""
+        for name, _, _ in SlotTables._FIELDS:
+            setattr(self, name, getattr(self._tables, name)[self._trow])
+
+    def _unbind(self, slot: int):
+        """The slot's task stops running here, or is carried past an apply
+        that did not decide it: its runtime keeps its batch and row."""
+        row = int(self._drow[slot])
+        if row >= 0:
+            rt = self._rt[slot]
+            rt._batch, rt._row, rt._cell = self._batch, row, None
+            self._drow[slot] = -1
+
+    def _bind(self, slot: int, row: int):
+        """The slot's task runs on ``row`` of the apply's batch."""
+        rt = self._rt[slot]
+        if rt is None:
+            rt = self._rt[slot] = TaskRuntime()
+        rt._cell, rt._at, rt._batch = self, slot, None
+        self._drow[slot] = row
 
     def _free_slot(self, slot: int):
-        """Detach a slot: cleared row, dirty, signatures reset so a future
-        re-seating re-dirties it even across a consuming sync."""
-        self._rid[slot] = -1
-        self._state[slot] = _FREE
-        self._pin[slot] = 0.0
-        self._dirty[slot] = True
-        self._sig_gen[slot] = -1
-        self._sig_pin[slot] = -1.0
-        self._req[slot] = None
-        self._row[slot] = None
-        self._rt[slot] = None
+        """Detach a slot (see ``_FREED``)."""
+        self._unbind(slot)
+        for name, value in _FREED:
+            getattr(self, name)[slot] = value
+        self._req[slot] = self._row[slot] = self._rt[slot] = None
         heapq.heappush(self._free_slots, slot)
+
+    def _drop_one(self, slot: int, rid: int):
+        """:func:`_drop` of one slot."""
+        _drop([self], np.zeros(1, np.int64), np.array([self._trow]),
+              np.array([slot]), [rid])
 
     def _enter(self, request: SliceRequest, retries: int, pin: float,
                runtime: TaskRuntime | None):
@@ -267,6 +367,13 @@ class CellRuntime:
         pin = p[2] if p is not None else float(self._pin[self._slot_of[rid]])
         return pin if pin > 0.0 else None
 
+    def gen_of(self, rid: int) -> int:
+        """The arrival generation of a live id."""
+        p = self._pending_in.get(rid)
+        if p is not None:
+            return p[4]
+        return int(self._gen[self._slot_of[rid]])
+
     def retries_left(self, rid: int) -> int:
         p = self._pending_in.get(rid)
         if p is not None:
@@ -333,8 +440,8 @@ class CellRuntime:
         slot = heapq.heappop(free) if free else self._hi
         if slot == self._hi:
             self._hi += 1
-            if self._hi > self._cap:
-                self._grow(self._hi)
+            if self._hi > self._tables.cap:
+                self._tables.grow(self._hi)
         self._rid[slot] = rid
         self._state[slot] = _QUEUED
         self._tier[slot] = req.tier
@@ -397,7 +504,8 @@ class CellRuntime:
             self._dirty[:hi] = False
         return self._row[:hi], dirty_now
 
-    def apply(self, decisions: list[SliceDecision]) -> list[SliceDecision]:
+    def apply(self, decisions: Sequence[SliceDecision]
+              ) -> Sequence[SliceDecision]:
         """Apply one re-slice round's decisions (for this cell's gather set).
 
         Admitted tasks keep (or gain) a runtime; rejected requests are NOT
@@ -409,68 +517,53 @@ class CellRuntime:
         submitted after the slot sync that produced ``decisions`` are
         untouched: they stay queued for the next round, and decisions for
         requests withdrawn (``remove()``) in the meantime are ignored.
+        ``decisions`` is a :class:`CellDecisions` or a list of
+        :class:`SliceDecision`; it is returned. See :func:`apply_decisions`,
+        which applies many cells' decisions at once, for how.
         """
-        prev = self.tasks
-        decided = {d.request.request_id for d in decisions}
-        # running tasks / queued requests the decisions do not cover (e.g.
-        # submitted between sync and apply) are carried forward untouched;
-        # this rebuild also purges the queue's lazy-deleted stale entries
-        self.tasks = {rid: rt for rid, rt in prev.items()
-                      if rid not in decided}
-        requeued: list[tuple[int, int]] = []
-        for rid in self.queued_ids():
-            if rid not in decided:
-                p = self._pending_in.get(rid)
-                gen = p[4] if p is not None \
-                    else int(self._gen[self._slot_of[rid]])
-                requeued.append((rid, gen))
-        self._queue = requeued
-        for d in decisions:
-            rid = d.request.request_id
-            slot = self._slot_of.get(rid)
-            if slot is None:
-                p = self._pending_in.pop(rid, None)
-                if p is None:
-                    # departed (remove()d) between sync and apply: the
-                    # decision is stale — do not resurrect or re-queue
-                    continue
-                # decided while still unseated (an apply without a prior
-                # slot sync — the gather()-based solve paths): seat now
-                slot = self._seat_one(rid, p)
-            tier = int(self._tier[slot])
-            self.offered_by_tier[tier] += 1
-            if d.admitted:
-                self.admitted_by_tier[tier] += 1
-                rt = self._rt[slot] or TaskRuntime(d)
-                rt.decision = d
-                self.tasks[rid] = rt
-                self._rt[slot] = rt
-                self._state[slot] = _RUNNING
-                continue
-            if rid in prev:
-                d.evicted = True
-                self.evictions += 1
-                self.evictions_by_tier[tier] += 1
-            # no served stream to warm-start from: a rejected task re-offers
-            # at its class threshold, not the pinned one
-            if self._pin[slot] != 0.0:
-                self._pin[slot] = 0.0
-                self._row[slot] = self._req[slot]
-            left = int(self._retries_left[slot]) - 1
-            self._retries_left[slot] = left
-            if left >= 0:
-                self._state[slot] = _QUEUED
-                self._queue.append((rid, int(self._gen[slot])))
-                # the task stays in the system: its job/latency history
-                # (kept in _rt as the parked carry) resumes on re-admission
-            else:
-                self.drops += 1
-                self.drops_by_tier[tier] += 1
-                self.dropped.append(self._req[slot])
-                self._slot_of.pop(rid)
-                self._free_slot(slot)
-                self._leave(rid)
-        return decisions
+        return apply_decisions([self], [decisions])[0]
+
+    def _apply_row(self, rid: int, admitted: bool, prev: dict,
+                   row: int) -> tuple[int, int, bool, bool]:
+        """Apply one decision, ``row`` of the apply's batch, by its request
+        id: the path of a row not seated in the slot it was decided in.
+        Returns the state it leaves the request in (-1: a stale decision),
+        its slot, whether that state changed, and whether the row is an
+        eviction."""
+        slot = self._slot_of.get(rid)
+        before = -1
+        if slot is None:
+            p = self._pending_in.pop(rid, None)
+            if p is None:
+                # departed (remove()d) between sync and apply: the decision
+                # is stale — do not resurrect or re-queue
+                return -1, -1, False, False
+            # decided while still unseated (an apply without a prior slot
+            # sync — the gather()-based solve paths): seat now
+            slot = self._seat_one(rid, p)
+        else:
+            before = int(self._state[slot])
+        tier = int(self._tier[slot])
+        self.offered_by_tier[tier] += 1
+        if admitted:
+            self.admitted_by_tier[tier] += 1
+            self._bind(slot, row)
+            self._state[slot] = _RUNNING
+            return _RUNNING, slot, before != _RUNNING, False
+        self._unbind(slot)
+        evicted = rid in prev
+        if evicted:
+            self.evictions += 1
+            self.evictions_by_tier[tier] += 1
+        if self._reject_one(slot):
+            return _QUEUED, slot, before != _QUEUED, evicted
+        self._drop_one(slot, rid)
+        return _FREE, slot, True, evicted
+
+    def _reject_one(self, slot: int) -> bool:
+        """:func:`_reject` of one slot: True if it re-queued."""
+        return bool(_reject([self], np.zeros(1, np.int64),
+                            np.array([self._trow]), np.array([slot]))[0])
 
     def preempt(self, request_id: int) -> bool:
         """Force-evict a RUNNING task (the post-solve preemption pass).
@@ -493,26 +586,16 @@ class CellRuntime:
                 f"request {request_id} is not running in cell {self.cell}")
         self.tasks.pop(request_id)
         slot = self._slot_of[request_id]
+        self._unbind(slot)
         tier = int(self._tier[slot])
         self.evictions += 1
         self.evictions_by_tier[tier] += 1
         self.preemptions += 1
         self.preemptions_by_tier[tier] += 1
-        if self._pin[slot] != 0.0:
-            self._pin[slot] = 0.0
-            self._row[slot] = self._req[slot]
-        left = int(self._retries_left[slot]) - 1
-        self._retries_left[slot] = left
-        if left >= 0:
-            self._state[slot] = _QUEUED
+        if self._reject_one(slot):
             self._queue.append((request_id, int(self._gen[slot])))
             return True
-        self.drops += 1
-        self.drops_by_tier[tier] += 1
-        self.dropped.append(self._req[slot])
-        self._slot_of.pop(request_id)
-        self._free_slot(slot)
-        self._leave(request_id)
+        self._drop_one(slot, request_id)
         return False
 
     def shed(self, request_id: int) -> SliceRequest:
@@ -686,6 +769,247 @@ class CellRuntime:
                            meets_deadline=False, no_data=True)
             out[rid] = rec
         return out
+
+
+def _reject(cells: Sequence[CellRuntime], k: np.ndarray, trow: np.ndarray,
+            slots: np.ndarray) -> np.ndarray:
+    """One retry consumed by each rejected entry (cell ``cells[k[i]]``, its
+    table row ``trow[i]``, slot ``slots[i]``), its pin cleared, and the
+    entries with budget left re-queued; returns which those are. Their queue
+    entries, and the drops of the others, are the caller's."""
+    if not slots.size:
+        return np.zeros(0, bool)
+    tab = cells[0]._tables
+    # no served stream to warm-start from: a rejected task re-offers at its
+    # class threshold, not the pinned one
+    pinned = np.flatnonzero(tab._pin[trow, slots] != 0.0)
+    if pinned.size:
+        tab._pin[trow[pinned], slots[pinned]] = 0.0
+        for c, a in zip(k[pinned].tolist(), slots[pinned].tolist()):
+            cells[c]._row[a] = cells[c]._req[a]
+    left = tab._retries_left[trow, slots] - 1
+    tab._retries_left[trow, slots] = left
+    keep = left >= 0
+    # the task stays in the system: its job/latency history (kept in _rt as
+    # the parked carry) resumes on re-admission
+    tab._state[trow[keep], slots[keep]] = _QUEUED
+    return keep
+
+
+def _drop(cells: Sequence[CellRuntime], k: np.ndarray, trow: np.ndarray,
+          slots: np.ndarray, rids: list[int]):
+    """Retry budgets exhausted: count and log each entry's drop (cell
+    ``cells[k[i]]``, table row ``trow[i]``, slot ``slots[i]``, request
+    ``rids[i]``), free its slot, take it out of the registry."""
+    if not slots.size:
+        return
+    tab = cells[0]._tables
+    _tally(cells, "drops_by_tier", k, tab._tier[trow, slots])
+    for name, value in _FREED:
+        getattr(tab, name)[trow, slots] = value
+    for c, a, rid in zip(k.tolist(), slots.tolist(), rids):
+        cell = cells[c]
+        cell.drops += 1
+        cell.dropped.append(cell._req[a])
+        del cell._slot_of[rid]
+        if cell._registry is not None:
+            cell._registry.pop(rid, None)
+        cell._req[a] = cell._row[a] = cell._rt[a] = None
+        heapq.heappush(cell._free_slots, a)
+
+
+def _tally(cells: Sequence[CellRuntime], counter: str, k: np.ndarray,
+           tiers: np.ndarray):
+    """``cells[k[i]].<counter>[tiers[i]] += 1`` for every ``i``."""
+    if not k.size:
+        return
+    low = int(tiers.min())
+    span = int(tiers.max()) - low + 1
+    counts = np.bincount(k * span + (tiers - low))
+    keys = np.flatnonzero(counts)
+    for key, n in zip(keys.tolist(), counts[keys].tolist()):
+        c, t = divmod(key, span)
+        getattr(cells[c], counter)[t + low] += n
+
+
+def apply_decisions(cells: Sequence[CellRuntime],
+                    decisions: Sequence[Sequence[SliceDecision]]) -> list:
+    """Apply one re-slice round's decisions, ``decisions[k]`` to
+    ``cells[k]`` (the semantics of :meth:`CellRuntime.apply`), as columns.
+
+    ``decisions[k]`` is a :class:`CellDecisions` or a sequence of
+    :class:`SliceDecision`, turned into one first. The cells share one
+    :class:`SlotTables`, so each table read or write is one ``(row, slot)``
+    gather or scatter over every cell's decided rows: the slot check (is
+    the row's request still seated where it was decided?), the per-tier
+    counters, the admitted and rejected rows' states, retries, pins and
+    freed slots. Python work is left to the rows whose state changes (a
+    runtime for a newly admitted task, an eviction flag, a drop), to the
+    rows that fail the slot check (decided unseated, or departed or
+    re-seated since: :meth:`CellRuntime._apply_row`), and to each cell's
+    ``tasks`` and queue, rebuilt in the order a row-by-row apply gives them.
+    A running task's runtime finds its decision through its slot's row of
+    the cell's newest batch (``_drow``, one scatter), bound once when it
+    starts running; the :class:`SliceDecision` is built only when read.
+    Returns ``decisions`` as a list.
+    """
+    decisions = list(decisions)
+    K = len(cells)
+    batches = [ds if isinstance(ds, CellDecisions) else CellDecisions.of(ds)
+               for ds in decisions]
+    sizes = [len(b) for b in batches]
+    ends = list(itertools.accumulate(sizes))
+    starts = [e - n for e, n in zip(ends, sizes)]
+    live = [b for b in batches if len(b)]
+    tab = cells[0]._tables
+    trows = np.fromiter((c._trow for c in cells), np.int64, K)
+    # the decided rows of all cells, in cell order: one slice of the
+    # solve's columns where the batches are its contiguous cuts
+    cols = live[0].columns if live else None
+    if cols is not None and cols.slot is not None and all(
+            b.columns is cols and b.lo == a.hi
+            for a, b in zip(live, live[1:])):
+        lo, hi = live[0].lo, live[-1].hi
+        rid, slot = cols.rid[lo:hi], cols.slot[lo:hi]
+        adm = cols.admitted[lo:hi]
+    elif live:
+        rid = np.concatenate([b.rid for b in batches])
+        adm = np.concatenate([b.admitted for b in batches])
+        slot = np.concatenate([
+            b.slot if b.slot is not None else np.fromiter(
+                (c._slot_of.get(r, -1) for r in b.rid.tolist()), np.int64,
+                len(b)) for c, b in zip(cells, batches)])
+    else:
+        rid = slot = np.zeros(0, np.int64)
+        adm = np.zeros(0, bool)
+    n = rid.size
+    rid_l = rid.tolist()
+    pos = np.repeat(np.arange(K), sizes)
+    trow = trows[pos]
+    fast = (slot >= 0) & (slot < tab.cap)
+    fast[fast] = tab._rid[trow[fast], slot[fast]] == rid[fast]
+    f = np.flatnonzero(fast)
+    fk, fr, ft = pos[f], trow[f], slot[f]
+    st = tab._state[fr, ft]                  # as the re-slice found them
+    tier = tab._tier[fr, ft]
+    fa = adm[f]
+    # what the carry-forward and the queue rebuild need, before any write:
+    # per cell, the decided rows that were running / seated and queued, and
+    # every seated queued request
+    prevs = [c.tasks for c in cells]
+    running = np.bincount(fk[st == _RUNNING], minlength=K).tolist()
+    queued = np.bincount(fk[st == _QUEUED], minlength=K).tolist()
+    waiting = np.count_nonzero(tab._state[trows] == _QUEUED, axis=1).tolist()
+    slow: dict[int, list[int]] = {}
+    for i in np.flatnonzero(~fast).tolist():
+        k = int(pos[i])
+        slow.setdefault(k, []).append(i)
+        running[k] += rid_l[i] in prevs[k]
+        a = cells[k]._slot_of.get(rid_l[i])
+        queued[k] += a is not None and cells[k]._state[a] == _QUEUED
+    # the rows in their slots, as masked updates
+    starts_a = np.asarray(starts, np.int64)
+    _tally(cells, "offered_by_tier", fk, tier)
+    _tally(cells, "admitted_by_tier", fk[fa], tier[fa])
+    # an admitted task's runtime reads its decision through its slot's row
+    # of the cell's batch: bound once, when it starts running there
+    kept = f[fa]
+    new = fa & (st != _RUNNING)
+    unbound = kept[tab._drow[fr[fa], ft[fa]] < 0]
+    tab._drow[fr[fa], ft[fa]] = kept - starts_a[pos[kept]]
+    for k, a, j in zip(pos[unbound].tolist(), slot[unbound].tolist(),
+                       (unbound - starts_a[pos[unbound]]).tolist()):
+        cells[k]._bind(a, j)
+    tab._state[fr[fa], ft[fa]] = _RUNNING
+    lost = f[~fa]
+    ev = st[~fa] == _RUNNING
+    evicted = lost[ev].tolist()
+    for k, a in zip(pos[lost[ev]].tolist(), slot[lost[ev]].tolist()):
+        cells[k].evictions += 1
+        cells[k]._unbind(a)
+    _tally(cells, "evictions_by_tier", pos[lost[ev]], tier[~fa][ev])
+    keep = _reject(cells, pos[lost], trow[lost], slot[lost])
+    # the state each row leaves its request in (-1: a stale decision)
+    state = np.full(n, -1, np.int8)
+    state[f[fa]] = _RUNNING
+    state[lost[keep]] = _QUEUED
+    gone = lost[~keep]
+    state[gone] = _FREE
+    moved = (np.bincount(fk[new], minlength=K)
+             + np.bincount(pos[lost[ev | ~keep]], minlength=K)).tolist()
+    at = slot
+    if slow:
+        # a cell's rows out of their slots seat, and its spent rows free
+        # theirs, in decision order: a seat takes the lowest slot free at
+        # its turn
+        at = slot.copy()
+        ordered = np.isin(pos[gone], list(slow))
+        for k, i in zip(pos[gone[ordered]].tolist(),
+                        gone[ordered].tolist()):
+            slow[k].append(i)
+        gone = gone[~ordered]
+        for k, rows in slow.items():
+            cell = cells[k]
+            for i in sorted(rows):
+                if state[i] == _FREE:
+                    cell._drop_one(int(at[i]), rid_l[i])
+                    continue
+                state[i], at[i], changed, was_running = cell._apply_row(
+                    rid_l[i], bool(adm[i]), prevs[k], i - starts[k])
+                moved[k] += changed
+                if was_running:
+                    evicted.append(i)
+    _drop(cells, pos[gone], trow[gone], slot[gone],
+          [rid_l[i] for i in gone.tolist()])
+    for i in evicted:
+        k = int(pos[i])
+        b = batches[k]
+        b.columns.flag_evicted(b.lo + i - starts[k])
+    won = np.flatnonzero(state == _RUNNING)
+    won_rid = rid[won].tolist()
+    won_at = at[won].tolist()
+    back = np.flatnonzero(state == _QUEUED)
+    requeue = list(zip(rid[back].tolist(),
+                       tab._gen[trow[back], at[back]].tolist()))
+    wcut = np.searchsorted(won, starts_a).tolist() + [won.size]
+    qcut = np.searchsorted(back, starts_a).tolist() + [back.size]
+    for k, cell in enumerate(cells):
+        # running tasks / queued requests the decisions do not cover (e.g.
+        # submitted between sync and apply) are carried forward untouched;
+        # this rebuild also purges the queue's lazy-deleted stale entries
+        prev = prevs[k]
+        ids = won_rid[wcut[k]:wcut[k + 1]]
+        if running[k] < len(prev):
+            skip = set(rid_l[starts[k]:ends[k]])
+            tasks = {r: rt for r, rt in prev.items() if r not in skip}
+            for r in tasks:
+                cell._unbind(cell._slot_of[r])
+        elif ids == list(prev):
+            tasks = None                     # the same tasks, in order
+        else:
+            tasks = {}
+        if tasks is None:
+            cell.tasks = prev.copy()
+        else:
+            rt_of = cell._rt
+            tasks.update(zip(ids, [rt_of[a]
+                                   for a in won_at[wcut[k]:wcut[k + 1]]]))
+            cell.tasks = tasks
+        cell._batch = batches[k]
+        queue = requeue[qcut[k]:qcut[k + 1]]
+        if queued[k] < waiting[k]:
+            skip = set(rid_l[starts[k]:ends[k]])
+            queue[:0] = [(r, cell.gen_of(r)) for r in cell.queued_ids()
+                         if r not in skip]
+        elif cell._pending_in:
+            # no seated request left undecided: what stays queued ahead of
+            # the re-queued rows is the unseated arrivals, in arrival order
+            skip = set(rid_l[starts[k]:ends[k]]) if k in slow else ()
+            queue[:0] = [(r, p[4]) for r, p in cell._pending_in.items()
+                         if r not in skip]
+        cell._queue = queue
+        cell.moved += moved[k]
+    return decisions
 
 
 class EdgeServingEngine:

@@ -103,7 +103,8 @@ from repro.core.latency import LatencyParams
 from repro.runtime.fault_tolerance import HeartbeatMonitor, StragglerMitigator
 from repro.trace import span
 from .admission import SESM, SliceDecision
-from .engine import CellRuntime, TaskRuntime, pinned_accuracy_at
+from .engine import (CellRuntime, SlotTables, TaskRuntime, apply_decisions,
+                     pinned_accuracy_at)
 from .request import SliceRequest
 from .sdla import SDLA
 
@@ -193,9 +194,12 @@ class MultiCellEngine:
         # enter/leave path (submit, hand-in/out, departure, drop, shed,
         # drain) — the O(1) locate() the event stream routes through
         self._cell_of: dict[int, int] = {}
+        # every cell's slot tables as one row each of a shared table, so a
+        # commit applies all the cells' decisions in one pass
+        tables = SlotTables(len(pools))
         self.cells = [CellRuntime(p, self.sdla, max_batch=max_batch,
                                   max_retries=max_retries, cell=c,
-                                  registry=self._cell_of)
+                                  registry=self._cell_of, tables=tables)
                       for c, p in enumerate(pools)]
         self.handovers = 0
         # ----------------------------------------------------- fault plane
@@ -624,9 +628,12 @@ class MultiCellEngine:
             decisions = pending.wait()
             if self.preempt:
                 decisions = self._preempt_pass(decisions)
-            with span("repro.tick.apply", tick=self.tick):
-                out = [cell.apply(ds)
-                       for cell, ds in zip(self.cells, decisions)]
+            with span("repro.tick.apply", tick=self.tick) as s:
+                moved = sum(cell.moved for cell in self.cells)
+                out = apply_decisions(self.cells, decisions)
+                s.set_metadata(
+                    rows=sum(len(ds) for ds in out),
+                    moved=sum(cell.moved for cell in self.cells) - moved)
             if self.freeze_heap and self.sesm._serve_session is not None \
                     and self.sesm._serve_session is not self._frozen_session:
                 self._freeze_heap()
@@ -744,7 +751,7 @@ class MultiCellEngine:
         decisions = self.sesm.solve_batch(self.gather(),
                                           coupling=self.coupling,
                                           pools=self.pools)
-        return [cell.apply(ds) for cell, ds in zip(self.cells, decisions)]
+        return apply_decisions(self.cells, decisions)
 
     def handover(self, request_id: int, src: int, dst: int) -> float:
         """Move a RUNNING task from cell ``src`` to cell ``dst``.

@@ -18,8 +18,8 @@ a collection), never per cell, event or request. Span tree of one tick::
       repro.solve.wait                      until the device result is ready
       repro.solve.fetch                     device-to-host copies
       repro.solve.unpack                    bit unpack, result dict
-      repro.sesm.decisions                  SliceDecision objects
-      repro.tick.apply                      every cell's apply
+      repro.sesm.decisions                  the tick's decision columns
+      repro.tick.apply                      apply_decisions, every cell
       repro.tick.freeze_heap                gc.collect + gc.freeze, a
                                             session's first commit only
     repro.gc.gen<N>                         one garbage collection
@@ -30,7 +30,9 @@ names it), so a reduction can pair each launch with the program's execution.
 It and ``repro.sesm.build_session`` also carry the allocation grid's size
 ``A`` and the number of distinct cell grids on it, ``grids`` (1 unless cells
 of different levels share a union grid); ``repro.sesm.sync_rows`` carries
-``masked``, the rows it recomputes under a cell mask that is not all true.
+``masked``, the rows it recomputes under a cell mask that is not all true;
+``repro.tick.apply`` carries ``rows``, the decided rows it applies, and
+``moved``, those whose state it changed.
 """
 
 from __future__ import annotations
